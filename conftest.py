@@ -1,35 +1,10 @@
-"""Pytest bootstrap for a clean checkout.
-
-1. Puts ``src/`` on sys.path so ``import repro`` works without an editable
-   install or PYTHONPATH (the tier-1 command still sets PYTHONPATH=src; both
-   paths lead to the same package).
-2. Installs the AbstractMesh signature compat so the sharding spec tests
-   (written against the modern ``AbstractMesh(sizes, names)`` API) run on
-   jax 0.4.3x too.
-3. If ``hypothesis`` is not installed, registers the minimal fallback in
-   ``tests/_hypothesis_fallback.py`` under the ``hypothesis`` name so the
-   property tests still collect and run (see pyproject.toml for the real
-   dependency).
-"""
-import importlib.util
+"""Pytest bootstrap for a clean checkout: puts ``src/`` on sys.path so
+``import repro`` works without an editable install or PYTHONPATH (the
+tier-1 command still sets PYTHONPATH=src; both paths lead to the same
+package)."""
 import os
 import sys
 
-_ROOT = os.path.dirname(os.path.abspath(__file__))
-_SRC = os.path.join(_ROOT, "src")
+_SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "src")
 if _SRC not in sys.path:
     sys.path.insert(0, _SRC)
-
-from repro.dist.compat import install_abstract_mesh_compat  # noqa: E402
-
-install_abstract_mesh_compat()
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    _spec = importlib.util.spec_from_file_location(
-        "hypothesis", os.path.join(_ROOT, "tests", "_hypothesis_fallback.py"))
-    _mod = importlib.util.module_from_spec(_spec)
-    _spec.loader.exec_module(_mod)
-    sys.modules["hypothesis"] = _mod
-    sys.modules["hypothesis.strategies"] = _mod.strategies

@@ -93,7 +93,9 @@ def main(argv: list[str] | None = None) -> int:
             print(e.code, file=sys.stderr)
             return 1
         return int(e.code or 0)
-    return int(ret or 0)
+    # A main() may return a result (serve: tokens, train: losses) rather
+    # than an exit status; only an int is a status.
+    return ret if isinstance(ret, int) else 0
 
 
 if __name__ == "__main__":

@@ -183,6 +183,11 @@ V5E_PEAK_FLOPS = 197e12        # bf16 FLOP/s per chip
 V5E_HBM_BW = 819e9             # bytes/s
 V5E_HBM_BYTES = 16 << 30
 V5E_VMEM_BYTES = 128 << 20
+#: Scoped VMEM every Pallas kernel is compiled under (``vmem_limit_bytes``)
+#: and the limit its planned tile must fit, double buffers included.  Half
+#: of the physical VMEM: Mosaic's own default scope (16 MiB) refuses the
+#: whole-hidden GRU tiles, and the other half stays free for XLA's fusions.
+V5E_VMEM_LIMIT_BYTES = 64 << 20
 V5E_ICI_BW = 50e9              # bytes/s per link
 V5E_CLOCK = 0.94e9
 

@@ -9,9 +9,8 @@ Outside the context every constraint is a transparent no-op, so kernels and
 models import nothing mesh-specific.
 
 ``sharding`` holds the pure PartitionSpec logic (no devices required — it
-works on ``jax.sharding.AbstractMesh``); ``compat`` papers over jax API
-drift so the same rules run on every supported jax version.
+works on ``jax.sharding.AbstractMesh``).
 """
-from . import compat, ctx, sharding
+from . import ctx, sharding
 
-__all__ = ["compat", "ctx", "sharding"]
+__all__ = ["ctx", "sharding"]

@@ -7,15 +7,17 @@ Pallas BlockSpec tiling.  The compiler output *is* the kernel configuration
 — no hand-written lowering rule.  ``plan_gemm`` / ``plan_gru`` are thin
 wrappers over ``compile_gemm`` / ``compile_gru``; tiles come from the
 ``CompiledKernel``'s role-keyed tile plan (derived from each mapping's
-``axis_map``), never from guessed haystack axis names.
+``axis_map``), never from guessed haystack axis names.  Both planners fit
+the tile to the VMEM limit the kernels are compiled under
+(``fit_gemm_block`` / ``fit_gru_block``), so a planned tile always compiles.
 """
 from __future__ import annotations
 
 import jax
 
 from ..compile import CompileError, compile_gemm, compile_gru
-from .gemm import gemm, gemm_bias_act, tuned_block
-from .gru import gru_cell, gru_seq
+from .gemm import fit_gemm_block, gemm, gemm_bias_act, tuned_block
+from .gru import fit_gru_block, gru_cell, gru_seq
 
 
 def plan_gemm(m: int, n: int, k: int, approach: str = "greedy",
@@ -35,23 +37,27 @@ def plan_gemm(m: int, n: int, k: int, approach: str = "greedy",
         except CACHE_ERRORS:
             rec = None
         if rec is not None and rec.tile:
-            return clamp_tile(rec.tile, m, n, k), rec.cost
+            return fit_gemm_block(clamp_tile(rec.tile, m, n, k)), rec.cost
     art = compile_gemm(m, n, k, approach=approach, use_cache=use_cache)
-    return art.gemm_tile(), art.cost
+    return fit_gemm_block(art.gemm_tile()), art.cost
 
 
 def plan_gru(batch: int, hidden: int, inp: int | None = None,
              approach: str = "greedy",
              use_cache: bool = True) -> tuple[tuple[int, int], float]:
     """Compile the GRU cell through ``repro.compile``; return the (bb, bh)
-    batch/hidden tile of its matmul stage + the modeled seconds.  Raises
-    ``CompileError`` if no matmul-shaped instruction was selected."""
+    batch/hidden tile of its matmul stage, fitted to the kernel's VMEM
+    limit, + the modeled seconds.  Raises ``CompileError`` if no
+    matmul-shaped instruction was selected."""
     art = compile_gru(batch, hidden, inp, approach=approach,
                       use_cache=use_cache)
     for prefix in ("fused.matmul", "mxu.matmul"):
         try:
             plan = art.instr_plan(prefix)
-            return (plan.tile_for("i"), plan.tile_for("j")), art.cost
+            tile = (plan.tile_for("i"), plan.tile_for("j"))
+            return (fit_gru_block(batch, hidden,
+                                  hidden if inp is None else inp, tile),
+                    art.cost)
         except CompileError:
             continue
     raise CompileError(
@@ -60,7 +66,7 @@ def plan_gru(batch: int, hidden: int, inp: int | None = None,
 
 
 def scheduled_gemm(a: jax.Array, b: jax.Array,
-                   interpret: bool | None = None) -> jax.Array:
+                   interpret: bool = False) -> jax.Array:
     """GEMM whose BlockSpec tiling was chosen by the compilation driver."""
     m, k = a.shape
     _, n = b.shape

@@ -1,5 +1,7 @@
 import os
+# A CPU-only tool: 512 placeholder host devices, and never the chip.
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run driver (deliverable e).
 

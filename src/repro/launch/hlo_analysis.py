@@ -117,8 +117,11 @@ TARGET_ROOFLINES = {
 
 def roofline_terms(flops: float, hbm_bytes: float, collective_bytes: float,
                    chips: int, target: str = "tpu_v5e") -> dict:
-    peak, hbm_bw, link_bw = TARGET_ROOFLINES.get(
-        target, TARGET_ROOFLINES["tpu_v5e"])
+    try:
+        peak, hbm_bw, link_bw = TARGET_ROOFLINES[target]
+    except KeyError:
+        raise KeyError(f"no roofline peaks for target {target!r}; known: "
+                       f"{sorted(TARGET_ROOFLINES)}") from None
     compute_s = flops / (chips * peak)
     memory_s = hbm_bytes / (chips * hbm_bw)
     collective_s = collective_bytes / (chips * link_bw)

@@ -8,20 +8,21 @@ import, and everything else must see the real (single) device.
 from __future__ import annotations
 
 import jax
-
-from ..dist import compat
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; multi-pod adds the 2-pod axis (512)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Arbitrary mesh for tests / elastic re-meshing."""
-    return compat.make_mesh(shape, axes)
+    """A mesh of the local devices whose axes are all ``Auto``: the
+    sharding rules place parameters and constrain activations, and the
+    compiler propagates the rest."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1):
@@ -31,4 +32,4 @@ def make_host_mesh(model: int = 1):
         raise ValueError(
             f"model axis {model} does not divide the {n} available "
             f"device(s); pass --model-axis dividing the device count")
-    return compat.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))

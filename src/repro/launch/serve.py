@@ -16,29 +16,36 @@ import numpy as np
 
 from ..configs import ARCHS, get_config, get_smoke_config
 from ..models import build_model
+from .compile_cache import enable_compile_cache
 
 
 def generate(model, params, batch, max_new: int, greedy: bool = True,
              rng=None):
-    """Prefill the prompt, then decode ``max_new`` tokens."""
+    """Prefill the prompt, then decode until ``max_new`` tokens exist.
+
+    Returns ``(tokens, logits)``: tokens (B, max_new) int32, and for each
+    token the (B, V) logits it was chosen from — ``logits[:, 0]`` from the
+    prefill, ``logits[:, i]`` from the i-th decode step."""
     cfg = model.cfg
     tokens = batch["tokens"]
     B, T = tokens.shape
     prefix = cfg.frontend_tokens if cfg.family == "vlm" else 0
     max_len = prefix + T + max_new
     cache, logits = model.prefill(params, batch, max_len=max_len)
-    out = []
-    cur = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+    logits = logits[:, -1]
+    out, seen = [], []
     for i in range(max_new):
-        out.append(cur)
-        pos = jnp.int32(prefix + T + i)
-        logits, cache = model.decode_step(params, cache, cur, pos)
+        if i:
+            pos = jnp.int32(prefix + T + i - 1)
+            logits, cache = model.decode_step(params, cache, out[-1], pos)
+        seen.append(logits)
         if greedy:
             cur = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         else:
             rng, k = jax.random.split(rng)
             cur = jax.random.categorical(k, logits).astype(jnp.int32)
-    return jnp.stack(out, axis=1)
+        out.append(cur)
+    return jnp.stack(out, axis=1), jnp.stack(seen, axis=1)
 
 
 def main(argv=None):
@@ -66,6 +73,7 @@ def main(argv=None):
                     help="learned cost model store (with --tuned): untuned "
                          "GEMM shapes get a model-predicted BlockSpec")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.tuned:
         from .train import activate_caches
@@ -92,8 +100,8 @@ def main(argv=None):
         ).astype(cfg.activation_dtype)
 
     t0 = time.perf_counter()
-    toks = generate(model, params, batch, args.gen,
-                    greedy=not args.sample, rng=k_gen)
+    toks, _ = generate(model, params, batch, args.gen,
+                       greedy=not args.sample, rng=k_gen)
     toks.block_until_ready()
     dt = time.perf_counter() - t0
     total = args.batch * args.gen

@@ -23,6 +23,7 @@ from ..dist.sharding import make_activation_rules, param_shardings, replicated
 from ..models.config import ModelConfig
 from ..optim.adamw import AdamWConfig, init_opt_state
 from ..runtime.fault_tolerance import TrainingRuntime
+from .compile_cache import enable_compile_cache
 from .mesh import make_host_mesh
 from .steps import make_train_step
 
@@ -128,6 +129,7 @@ def main(argv=None):
                          "model-predicted BlockSpec "
                          "(train one: python -m repro.search.model train)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.tuned:
         activate_caches(args.tuning_cache, args.compile_cache,

@@ -11,7 +11,7 @@ because:
     powers of two) is exact over the dyadic values the tracer's
     ``block_inputs`` generates, in *any* summation order — XLA's, numpy's,
     or the ISAMIR interpreter's;
-  * the reference computes in float64 (``jax.experimental.enable_x64``) and
+  * the reference computes in float64 (``jax.enable_x64``) and
     casts to float32 at exactly the traced node boundaries, mirroring the
     graph interpreter's per-node dtype casts.
 
@@ -40,8 +40,8 @@ def block_reference(inputs: dict[str, np.ndarray], cfg: ModelConfig,
     ``wk{h}`` / ``wv{h}`` / ``wo{h}``, and ``w_gate`` / ``w_up`` /
     ``w_down``.
     """
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     H, Dh = cfg.n_heads, cfg.hd
     halvings = (Dh.bit_length() - 1) // 2
@@ -49,7 +49,7 @@ def block_reference(inputs: dict[str, np.ndarray], cfg: ModelConfig,
         raise ValueError(f"head_dim {Dh} is not a power of 4")
     scale = 2.0 ** -halvings
 
-    with enable_x64():
+    with jax.enable_x64(True):
         t = {k: jnp.asarray(np.asarray(v), jnp.float64)
              for k, v in inputs.items()}
         x = _boundary(t["x"])

@@ -16,9 +16,8 @@ Three ways to score a config vector:
 
   * ``MeasuredGemmEvaluator`` — optional wall-clock: forward the candidate's
     tile choice as the Pallas GEMM BlockSpec (``kernels/gemm.py``) and time
-    the kernel.  Only meaningful on a real TPU backend; on CPU the kernel
-    runs in interpret mode, which is numerically faithful but slow, so the
-    tuner defaults to the cost backend.
+    the kernel.  It runs on a TPU backend only and raises anywhere else;
+    the tuner defaults to the cost backend.
 
 ``validate_selection`` replays a schedule through ``core.executor`` against
 the ``ir.interpret`` oracle.  Because every unroll policy in the search
@@ -335,28 +334,24 @@ def gemm_tile_for(config: Config, graph: SystemGraph,
 
 class MeasuredGemmEvaluator:
     """Score a config by timing the Pallas GEMM with the candidate's tile
-    choice as the BlockSpec.  jax is imported lazily so the cost-model path
-    stays numpy-only."""
+    choice as the BlockSpec.  Only a TPU can be timed: any other backend
+    raises.  jax is imported lazily so the cost-model path stays
+    numpy-only."""
 
     def __init__(self, m: int, n: int, k: int, graph: SystemGraph,
-                 repeats: int = 3, interpret: bool | None = None):
-        import warnings
-
+                 repeats: int = 3):
         import jax
         import jax.numpy as jnp
         from ..kernels.gemm import gemm
         if jax.default_backend() != "tpu":
-            warnings.warn(
-                f"measured GEMM tuning on the {jax.default_backend()!r} "
-                "backend runs Pallas in interpret mode — numerically "
-                "faithful but extremely slow on large shapes; wall-clock "
-                "results are only meaningful on TPU (use --backend cost)",
-                stacklevel=2)
+            raise RuntimeError(
+                f"the measure backend times Pallas kernels on a TPU; this "
+                f"process has the {jax.default_backend()!r} backend "
+                "(use --backend cost)")
         self._gemm = gemm
         self.m, self.n, self.k = m, n, k
         self.graph = graph
         self.repeats = repeats
-        self.interpret = interpret
         key = jax.random.PRNGKey(0)
         ka, kb = jax.random.split(key)
         self.a = jax.random.uniform(ka, (m, k), jnp.float32, -1, 1)
@@ -368,20 +363,23 @@ class MeasuredGemmEvaluator:
         return gemm_tile_for(config, self.graph, self.m, self.n, self.k)
 
     def __call__(self, config: Config) -> float:
+        import jax
         block = self.block_for(config)
         try:
-            out = self._gemm(self.a, self.b, block=block,
-                             interpret=self.interpret)
+            out = self._gemm(self.a, self.b, block=block)
             out.block_until_ready()          # compile + warm
-            best = float("inf")
-            for _ in range(self.repeats):
-                t0 = time.perf_counter()
-                self._gemm(self.a, self.b, block=block,
-                           interpret=self.interpret).block_until_ready()
-                best = min(best, time.perf_counter() - t0)
-            return best
-        except Exception:
+        except jax.errors.JaxRuntimeError as e:
+            # A tile the chip's compiler refuses for want of VMEM is an
+            # infeasible candidate; every other failure is a fault.
+            if "RESOURCE_EXHAUSTED" not in str(e):
+                raise
             return float("inf")
+        best = float("inf")
+        for _ in range(self.repeats):
+            t0 = time.perf_counter()
+            self._gemm(self.a, self.b, block=block).block_until_ready()
+            best = min(best, time.perf_counter() - t0)
+        return best
 
 
 # --------------------------------------------------------------------------- #

@@ -389,8 +389,9 @@ def main(argv=None) -> int:
                          "learned always runs 'surrogate')")
     ap.add_argument("--backend", choices=["cost", "measure", "learned"],
                     default="cost",
-                    help="'measure' times the Pallas GEMM (TPU-meaningful; "
-                         "falls back to 'cost' for non-GEMM cases); "
+                    help="'measure' times the Pallas GEMM on the TPU "
+                         "(fails on any other backend; falls back to "
+                         "'cost' for non-GEMM cases); "
                          "'learned' runs surrogate-guided search — a "
                          "trained repro.search.model ranks the pool, the "
                          "cost model settles the real trials (falls back "
@@ -423,6 +424,11 @@ def main(argv=None) -> int:
     strategy = args.strategy or ("surrogate" if args.backend == "learned"
                                  else "hillclimb")
 
+    if args.workers > 1 and args.backend == "measure":
+        # Each worker process would need the chip, which one process owns.
+        print("--backend measure times kernels on the chip, which one "
+              "process owns; run it with --workers 1", file=sys.stderr)
+        return 2
     if args.target and args.graph and args.target != args.graph:
         print(f"--target {args.target} and --graph {args.graph} disagree; "
               "pass one of them", file=sys.stderr)

@@ -93,3 +93,12 @@ ENTRY %main.2 (buf: f32[1024,1024], upd: f32[1,1024]) -> f32[1024,1024] {
     st = analyse_hlo(text)
     # ~2 x update bytes (+ scalar indices), NOT ~2 x 4MB buffer
     assert 2 * 1024 * 4 <= st.bytes_accessed < 2 * 1024 * 4 + 64
+
+
+def test_roofline_terms_refuse_unknown_target():
+    from repro.launch.hlo_analysis import roofline_terms
+    terms = roofline_terms(197e12, 819e9, 0.0, 1, target="tpu_v5e")
+    assert terms["compute_s"] == pytest.approx(1.0)
+    assert terms["memory_s"] == pytest.approx(1.0)
+    with pytest.raises(KeyError, match="no roofline peaks"):
+        roofline_terms(1.0, 1.0, 0.0, 1, target="tpu_v9")

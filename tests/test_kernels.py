@@ -1,12 +1,15 @@
 """Pallas kernel tests: shape/dtype sweeps in interpret mode against the
 pure-jnp oracles, plus the ISAM->BlockSpec bridge."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.sysgraph import V5E_VMEM_LIMIT_BYTES
 from repro.kernels import ops, ref
-from repro.kernels.gemm import gemm, gemm_bias_act
-from repro.kernels.gru import PARAM_NAMES, gru_cell, gru_seq
+from repro.kernels.gemm import (fit_gemm_block, gemm, gemm_bias_act,
+                                gemm_vmem_bytes)
+from repro.kernels.gru import PARAM_NAMES, gru_cell, gru_seq, gru_vmem_bytes
 
 
 def rand(rng, shape, dtype):
@@ -110,3 +113,30 @@ def test_scheduled_gemm_executes():
     got = ops.scheduled_gemm(a, b, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref.gemm_ref(a, b)),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_kernels_compile_for_the_chip_unless_told_to_interpret():
+    """No silent interpret-mode fallback: off a TPU, a kernel called
+    without interpret=True fails instead of running in the interpreter."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("runs the kernel on the chip here")
+    a = jnp.ones((128, 128), jnp.float32)
+    with pytest.raises(ValueError, match="interpret"):
+        jax.block_until_ready(gemm(a, a, block=(128, 128, 128)))
+
+
+@pytest.mark.parametrize("batch,hidden", [(32, 512), (32, 1024), (16, 1536),
+                                          (32, 1792), (128, 2560)])
+def test_planned_gru_tile_fits_vmem_limit(batch, hidden):
+    tile, _ = ops.plan_gru(batch, hidden, use_cache=False)
+    assert gru_vmem_bytes(*tile, hidden, hidden) <= V5E_VMEM_LIMIT_BYTES
+    assert tile[1] == hidden or tile[1] % 128 == 0
+
+
+def test_fit_gemm_block_shrinks_an_oversized_tile():
+    big = (512, 4096, 4096)
+    assert gemm_vmem_bytes(big) > V5E_VMEM_LIMIT_BYTES
+    fitted = fit_gemm_block(big)
+    assert gemm_vmem_bytes(fitted) <= V5E_VMEM_LIMIT_BYTES
+    assert all(f <= b for f, b in zip(fitted, big))
+    assert fit_gemm_block((128, 128, 1024)) == (128, 128, 1024)

@@ -287,3 +287,19 @@ def test_bench_run_json_output(tmp_path):
     assert data["failures"] == 0
     assert data["rows"] and all("suite" in r and "us_per_call" in r
                                 for r in data["rows"])
+
+
+def test_measure_backend_refuses_a_host_without_tpu():
+    """Wall-clock tuning times the chip; on any other backend it raises
+    instead of timing the Pallas interpreter."""
+    from repro.search.evaluate import MeasuredGemmEvaluator
+    with pytest.raises(RuntimeError, match="TPU"):
+        MeasuredGemmEvaluator(64, 64, 64, tpu_v5e(1))
+
+
+def test_measure_backend_refuses_parallel_workers(tmp_path, capsys):
+    from repro.search.tune import main
+    rc = main(["--suite", "gemm", "--limit", "1", "--backend", "measure",
+               "--workers", "2", "--cache", str(tmp_path / "c.json")])
+    assert rc == 2
+    assert "--workers 1" in capsys.readouterr().err

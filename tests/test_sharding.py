@@ -1,27 +1,13 @@
 """Sharding-rule unit tests over an abstract 16x16 production mesh — no
 devices required (PartitionSpec logic only)."""
-import jax
-import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import get_config
 from repro.dist.sharding import (batch_spec, cache_spec, dp_axes, param_spec,
                                  shard_dim)
 
-# AbstractMesh and its (axis_sizes, axis_names) signature are recent jax
-# API; repro.dist.compat bridges 0.4.3x (installed via conftest.py), but on
-# a jax that predates AbstractMesh entirely these spec tests cannot build
-# their device-free meshes — skip with a clear message instead of crashing
-# the whole collection.
-try:
-    from jax.sharding import AbstractMesh
-    MESH = AbstractMesh((16, 16), ("data", "model"))
-    MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
-except (ImportError, TypeError) as e:
-    pytest.skip(
-        f"jax {jax.__version__} has no usable jax.sharding.AbstractMesh "
-        f"({e}); abstract-mesh sharding spec tests need jax>=0.4.35",
-        allow_module_level=True)
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_dp_axes():

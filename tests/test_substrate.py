@@ -1,6 +1,7 @@
 """Training-substrate tests: optimizer, data determinism, checkpointing,
 fault-tolerance runtime, end-to-end smoke training with restart."""
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -185,3 +186,17 @@ def test_train_driver_loss_decreases(tmp_path):
                    "--batch", "8", "--seq", "64",
                    "--ckpt-dir", str(tmp_path)])
     assert losses[-1] < losses[0] - 0.3
+
+
+@pytest.mark.parametrize("argv", [
+    ["serve", "--arch", "olmo-1b", "--smoke", "--batch", "2",
+     "--prompt-len", "8", "--gen", "4"],
+    ["train", "--arch", "olmo-1b", "--smoke", "--steps", "2", "--batch", "2",
+     "--seq", "16", "--ckpt-dir", "{ckpt}"],
+])
+def test_cli_entry_point_exits_zero(argv, tmp_path, monkeypatch):
+    """``repro serve``/``repro train`` return results (tokens, losses), not
+    statuses; the console script still exits 0 after them."""
+    from repro import cli
+    monkeypatch.setattr(sys, "argv", list(sys.argv))
+    assert cli.main([a.format(ckpt=tmp_path) for a in argv]) == 0
