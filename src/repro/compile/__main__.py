@@ -7,9 +7,12 @@
 Compiles workloads through the full pipeline (Map → Select → Schedule →
 Lower) and prints one ``CompiledKernel`` summary per case: the role-derived
 tile, the lowering config, the modeled cost, and whether the artifact came
-from the persistent cache.  ``--validate`` replays each schedule through
-``core.executor`` against the ``ir.interpret`` oracle on a proxy-capped
-shape and requires bit-exactness.  ``--expect-cached`` fails unless every
+from the persistent cache, then the host milliseconds of the compile and
+of each pipeline pass that ran (the ``isam.compile`` and ``isam.<pass>``
+spans of ``repro.runtime.spans``; a cache hit runs none).  ``--validate``
+replays each schedule through ``core.executor`` against the
+``ir.interpret`` oracle on a proxy-capped shape and requires
+bit-exactness.  ``--expect-cached`` fails unless every
 case hit the cache (CI uses it to prove artifact reuse).
 
 Multi-chip: ``--chips N --topology ring|torus|host`` compiles the fabric
@@ -23,8 +26,10 @@ from __future__ import annotations
 import argparse
 import json
 import sys
+import time
 
 from ..core.sysgraph import TARGET_ALIASES, TARGETS, resolve_target
+from ..runtime.spans import records, summarize
 from .artifact import CompileError
 from .cache import ArtifactCache, set_default_artifact_cache
 from .driver import (compile_conv, compile_fabric, compile_gemm, compile_gru,
@@ -98,6 +103,14 @@ def _validate(kernel: str, kw: dict, approach, graph):
     return validate_schedule(orig, sel, art.ensure_schedule())
 
 
+def _compile_ms(t0: float) -> dict:
+    """Host milliseconds of the pipeline since ``t0``: ``compile`` for the
+    whole, and one entry per pass that ran."""
+    s = summarize(records(t0, time.perf_counter()))
+    return {name.removeprefix("isam."): 1e3 * v["seconds"]
+            for name, v in s.items() if name.startswith("isam.")}
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m repro.compile",
@@ -168,6 +181,7 @@ def main(argv=None) -> int:
     rows = []
     failures = 0
     for kernel, kw in cases:
+        t0 = time.perf_counter()
         try:
             art = _compile_case(kernel, kw, approach, args, graph)
         except CompileError as e:
@@ -178,7 +192,7 @@ def main(argv=None) -> int:
                "graph": art.graph_name, "cost_s": art.cost,
                "lowering": art.lowering, "cached": art.from_cache,
                "counts": art.counts, "bytes_moved": art.bytes_moved,
-               "key": art.key}
+               "key": art.key, "compile_ms": _compile_ms(t0)}
         try:
             row["tile"] = list(art.gemm_tile())
         except CompileError:
@@ -199,6 +213,10 @@ def main(argv=None) -> int:
                 failures += 1
         rows.append(row)
         print(f"[{status}] {art.summary()}")
+        ms = dict(row["compile_ms"])
+        if ms:
+            print(f"    compile {ms.pop('compile'):.2f} ms: " + ", ".join(
+                f"{name} {v:.2f}" for name, v in ms.items()))
     if args.json:
         with open(args.json, "w") as f:
             json.dump({"schema": 1, "approach": args.approach,

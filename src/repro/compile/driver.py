@@ -187,9 +187,7 @@ def compile_program(program: Program, graph: SystemGraph | None = None,
                          backend=backend, verify=verify,
                          meta=dict(meta or {}))
     ctx.meta.setdefault("allow_transforms", allow_transforms)
-    MapPass().run(ctx)
-    SelectPass().run(ctx)
-    return _finish(ctx, cache, memoize=use_cache)
+    return _store(Pipeline().run(ctx), cache, memoize=use_cache)
 
 
 def compile_selection(selection: Selection, graph: SystemGraph,
@@ -199,7 +197,8 @@ def compile_selection(selection: Selection, graph: SystemGraph,
                       meta: dict | None = None) -> CompiledKernel:
     """Schedule + Lower an existing Selection (no caching: this is the hot
     inner entry the search evaluators and per-chip fabric compiles use, so
-    the static verifier is opt-in here — pass ``verify=True`` to gate)."""
+    the static verifier is opt-in here — pass ``verify=True`` to gate — and
+    no spans are recorded)."""
     approach = resolve_approach(approach)
     ctx = CompileContext(program=program or selection.program, graph=graph,
                          approach=approach, backend=backend,
@@ -207,7 +206,7 @@ def compile_selection(selection: Selection, graph: SystemGraph,
     ctx.selection = selection
     passes = ((SchedulePass(), VerifyPass(), LowerPass()) if verify
               else (SchedulePass(), LowerPass()))
-    return Pipeline(passes=passes).run(ctx)
+    return Pipeline(passes=passes, spans=False).run(ctx)
 
 
 def _compile_frontend(frontend: str, fe_args: dict, graph, approach, backend,

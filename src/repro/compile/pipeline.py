@@ -22,6 +22,7 @@ ad-hoc call chains.
 """
 from __future__ import annotations
 
+import contextlib
 import math
 from dataclasses import dataclass, field
 
@@ -31,6 +32,7 @@ from ..core.isel import (Selection, candidate_instructions,
                          select_from_candidates)
 from ..core.scheduler import Schedule, ScheduleError, schedule
 from ..core.sysgraph import SystemGraph
+from ..runtime.spans import span
 from .artifact import CompiledKernel, CompileError, InstrPlan
 from .cache import (approach_fingerprint, artifact_key_from_parts,
                     isa_fingerprint)
@@ -213,21 +215,32 @@ DEFAULT_PASSES = (MapPass(), SelectPass(), SchedulePass(), VerifyPass(),
                   LowerPass())
 
 
+def _no_span(name: str, **attrs):
+    return contextlib.nullcontext()
+
+
 @dataclass
 class Pipeline:
-    """An ordered pass list + artifact assembly."""
+    """An ordered pass list + artifact assembly.  With ``spans`` (the
+    default) ``run`` records the span ``isam.compile`` and under it one
+    ``isam.<pass>`` per pass (``repro.runtime.spans``); the search and
+    fabric loops compile once per candidate and pass ``spans=False``."""
 
     passes: tuple = DEFAULT_PASSES
+    spans: bool = True
 
     def run(self, ctx: CompileContext) -> CompiledKernel:
         approach = ctx.approach if ctx.approach is not None else GreedyApproach()
         ctx.approach = approach
-        try:
-            for p in self.passes:
-                p.run(ctx)
-        except ScheduleError as e:
-            raise CompileError(str(e)) from e
-        return self.assemble(ctx)
+        mark = span if self.spans else _no_span
+        with mark("isam.compile", program=ctx.program.name):
+            try:
+                for p in self.passes:
+                    with mark(f"isam.{p.name}"):
+                        p.run(ctx)
+            except ScheduleError as e:
+                raise CompileError(str(e)) from e
+            return self.assemble(ctx)
 
     @staticmethod
     def assemble(ctx: CompileContext) -> CompiledKernel:

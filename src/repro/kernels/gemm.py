@@ -139,6 +139,9 @@ def gemm(a: jax.Array, b: jax.Array,
     cache update needs a fresh process or jit cache).  Inputs whose
     dimensions don't divide the block are padded up and the result cropped;
     zero padding is exact for the contraction.
+
+    The kernel is named ``isam_gemm``; the pad runs under the named scope
+    ``isam_gemm.pad``, the crop and cast under ``isam_gemm.crop``.
     """
     m, k = a.shape
     k2, n = b.shape
@@ -149,8 +152,9 @@ def gemm(a: jax.Array, b: jax.Array,
 
     acc_dtype = jnp.float32 if a.dtype in (jnp.bfloat16, jnp.float32) else a.dtype
     mp, np_, kp = _cdiv(m, bm) * bm, _cdiv(n, bn) * bn, _cdiv(k, bk) * bk
-    a_p = jnp.pad(a, ((0, mp - m), (0, kp - k))) if (mp, kp) != (m, k) else a
-    b_p = jnp.pad(b, ((0, kp - k), (0, np_ - n))) if (kp, np_) != (k, n) else b
+    with jax.named_scope("isam_gemm.pad"):
+        a_p = jnp.pad(a, ((0, mp - m), (0, kp - k))) if (mp, kp) != (m, k) else a
+        b_p = jnp.pad(b, ((0, kp - k), (0, np_ - n))) if (kp, np_) != (k, n) else b
 
     grid = (mp // bm, np_ // bn, kp // bk)
     out = pl.pallas_call(
@@ -164,8 +168,10 @@ def gemm(a: jax.Array, b: jax.Array,
         out_shape=jax.ShapeDtypeStruct((mp, np_), acc_dtype),
         interpret=interpret,
         compiler_params=COMPILER_PARAMS,
+        name="isam_gemm",
     )(a_p, b_p)
-    return out[:m, :n].astype(a.dtype)
+    with jax.named_scope("isam_gemm.crop"):
+        return out[:m, :n].astype(a.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret", "fn"))
@@ -182,9 +188,10 @@ def gemm_bias_act(a: jax.Array, b: jax.Array, bias: jax.Array,
         block = tuned_block(m, n, k)
     bm, bn, bk = (min(block[0], m), min(block[1], n), min(block[2], k))
     mp, np_, kp = _cdiv(m, bm) * bm, _cdiv(n, bn) * bn, _cdiv(k, bk) * bk
-    a_p = jnp.pad(a, ((0, mp - m), (0, kp - k))) if (mp, kp) != (m, k) else a
-    b_p = jnp.pad(b, ((0, kp - k), (0, np_ - n))) if (kp, np_) != (k, n) else b
-    bias_p = jnp.pad(bias, (0, np_ - n)) if np_ != n else bias
+    with jax.named_scope("isam_gemm_bias_act.pad"):
+        a_p = jnp.pad(a, ((0, mp - m), (0, kp - k))) if (mp, kp) != (m, k) else a
+        b_p = jnp.pad(b, ((0, kp - k), (0, np_ - n))) if (kp, np_) != (k, n) else b
+        bias_p = jnp.pad(bias, (0, np_ - n)) if np_ != n else bias
     grid = (mp // bm, np_ // bn, kp // bk)
 
     def kernel(a_ref, b_ref, bias_ref, c_ref):
@@ -219,5 +226,7 @@ def gemm_bias_act(a: jax.Array, b: jax.Array, bias: jax.Array,
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         interpret=interpret,
         compiler_params=COMPILER_PARAMS,
+        name="isam_gemm_bias_act",
     )(a_p, b_p, bias_p)
-    return out[:m, :n].astype(a.dtype)
+    with jax.named_scope("isam_gemm_bias_act.crop"):
+        return out[:m, :n].astype(a.dtype)

@@ -98,7 +98,8 @@ def fit_gru_block(batch: int, hidden: int, inp: int,
 def gru_cell(x: jax.Array, h: jax.Array, params: dict,
              block: tuple[int, int] = (128, 128),
              interpret: bool = False) -> jax.Array:
-    """One fused GRU step: x (B, E), h (B, H) -> h' (B, H)."""
+    """One fused GRU step: x (B, E), h (B, H) -> h' (B, H); the kernel is
+    named ``isam_gru_cell``."""
     B, E = x.shape
     _, H = h.shape
     bb, bh = min(block[0], B), min(block[1], H)
@@ -135,6 +136,7 @@ def gru_cell(x: jax.Array, h: jax.Array, params: dict,
         out_shape=jax.ShapeDtypeStruct((Bp, Hp), x.dtype),
         interpret=interpret,
         compiler_params=COMPILER_PARAMS,
+        name="isam_gru_cell",
     )(x_p, h_p, h_p,
       padw(params["Wr"]), padu(params["Ur"]),
       padw(params["Wz"]), padu(params["Uz"]),

@@ -3,6 +3,10 @@ decode steps with a KV/recurrent cache.
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-7b --smoke \
         --batch 4 --prompt-len 16 --gen 24
+
+It prints one JSON record; its ``spans`` summarize the ``generate`` call by
+span name (``repro.runtime.spans.summarize``): count, host seconds, and
+JAX's traces, lowerings and compiles.
 """
 from __future__ import annotations
 
@@ -16,6 +20,7 @@ import numpy as np
 
 from ..configs import ARCHS, get_config, get_smoke_config
 from ..models import build_model
+from ..runtime.spans import records, span, summarize
 from .compile_cache import enable_compile_cache
 
 
@@ -25,27 +30,38 @@ def generate(model, params, batch, max_new: int, greedy: bool = True,
 
     Returns ``(tokens, logits)``: tokens (B, max_new) int32, and for each
     token the (B, V) logits it was chosen from — ``logits[:, 0]`` from the
-    prefill, ``logits[:, i]`` from the i-th decode step."""
+    prefill, ``logits[:, i]`` from the i-th decode step.
+
+    Records the spans ``generate`` (the root), ``generate.prefill``,
+    ``generate.decode`` and one ``generate.decode_step`` per step
+    (``repro.runtime.spans``)."""
     cfg = model.cfg
     tokens = batch["tokens"]
     B, T = tokens.shape
     prefix = cfg.frontend_tokens if cfg.family == "vlm" else 0
     max_len = prefix + T + max_new
-    cache, logits = model.prefill(params, batch, max_len=max_len)
-    logits = logits[:, -1]
-    out, seen = [], []
-    for i in range(max_new):
-        if i:
-            pos = jnp.int32(prefix + T + i - 1)
-            logits, cache = model.decode_step(params, cache, out[-1], pos)
-        seen.append(logits)
+
+    def pick(logits):
+        nonlocal rng
         if greedy:
-            cur = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            rng, k = jax.random.split(rng)
-            cur = jax.random.categorical(k, logits).astype(jnp.int32)
-        out.append(cur)
-    return jnp.stack(out, axis=1), jnp.stack(seen, axis=1)
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        rng, k = jax.random.split(rng)
+        return jax.random.categorical(k, logits).astype(jnp.int32)
+
+    with span("generate", batch=B, prompt_len=T, max_new=max_new):
+        with span("generate.prefill"):
+            cache, logits = model.prefill(params, batch, max_len=max_len)
+        seen = [logits[:, -1]]
+        out = [pick(seen[-1])]
+        with span("generate.decode", steps=max_new - 1):
+            for i in range(1, max_new):
+                pos = jnp.int32(prefix + T + i - 1)
+                with span("generate.decode_step", step=i):
+                    logits, cache = model.decode_step(params, cache, out[-1],
+                                                      pos)
+                seen.append(logits)
+                out.append(pick(logits))
+        return jnp.stack(out, axis=1), jnp.stack(seen, axis=1)
 
 
 def main(argv=None):
@@ -103,14 +119,11 @@ def main(argv=None):
     toks, _ = generate(model, params, batch, args.gen,
                        greedy=not args.sample, rng=k_gen)
     toks.block_until_ready()
-    dt = time.perf_counter() - t0
-    total = args.batch * args.gen
     record = {
         "arch": cfg.name, "batch": args.batch,
         "prompt_len": args.prompt_len, "generated": args.gen,
-        "greedy": not args.sample,
-        "tokens": int(total), "wall_s": round(dt, 3),
-        "tok_per_s": round(total / dt, 2),
+        "greedy": not args.sample, "tokens": args.batch * args.gen,
+        "spans": summarize(records(t0, time.perf_counter())),
         "sample": np.asarray(toks[0, :8]).tolist(),
     }
     print(json.dumps(record))

@@ -145,19 +145,20 @@ def prefill_attention(p, x, cfg: ModelConfig, max_len: int = 0):
     out = constrain(out, "heads").reshape(B, T, H * cfg.hd)
     out = constrain(jnp.dot(out, p["wo"]), "residual")
     max_len = max(max_len, T)
-    if cfg.sliding_window:
-        S = min(cfg.sliding_window, max_len)
-        if T > S:
-            k, v = k[:, -S:], v[:, -S:]
-        elif S > T:
-            k = jnp.pad(k, ((0, 0), (0, S - T), (0, 0), (0, 0)))
-            v = jnp.pad(v, ((0, 0), (0, S - T), (0, 0), (0, 0)))
-        # rolling-buffer layout: position p lives at slot p % S
-        k = jnp.roll(k, T % S if T > S else 0, axis=1)
-        v = jnp.roll(v, T % S if T > S else 0, axis=1)
-    elif max_len > T:
-        k = jnp.pad(k, ((0, 0), (0, max_len - T), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, max_len - T), (0, 0), (0, 0)))
+    with jax.named_scope("kv_update"):
+        if cfg.sliding_window:
+            S = min(cfg.sliding_window, max_len)
+            if T > S:
+                k, v = k[:, -S:], v[:, -S:]
+            elif S > T:
+                k = jnp.pad(k, ((0, 0), (0, S - T), (0, 0), (0, 0)))
+                v = jnp.pad(v, ((0, 0), (0, S - T), (0, 0), (0, 0)))
+            # rolling-buffer layout: position p lives at slot p % S
+            k = jnp.roll(k, T % S if T > S else 0, axis=1)
+            v = jnp.roll(v, T % S if T > S else 0, axis=1)
+        elif max_len > T:
+            k = jnp.pad(k, ((0, 0), (0, max_len - T), (0, 0), (0, 0)))
+            v = jnp.pad(v, ((0, 0), (0, max_len - T), (0, 0), (0, 0)))
     return out, {"k": k, "v": v}
 
 
@@ -174,8 +175,9 @@ def decode_attention(p: dict, x: jax.Array, cache: dict, pos: jax.Array,
     k = apply_rotary(k, cos, sin)
 
     slot = pos % S if cfg.sliding_window else pos
-    ck = jax.lax.dynamic_update_slice(cache["k"], k, (0, slot, 0, 0))
-    cv = jax.lax.dynamic_update_slice(cache["v"], v, (0, slot, 0, 0))
+    with jax.named_scope("kv_update"):
+        ck = jax.lax.dynamic_update_slice(cache["k"], k, (0, slot, 0, 0))
+        cv = jax.lax.dynamic_update_slice(cache["v"], v, (0, slot, 0, 0))
 
     kk = _expand_kv(ck, H)   # (B, S, H, hd)
     vv = _expand_kv(cv, H)

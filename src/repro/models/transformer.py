@@ -55,28 +55,39 @@ def _norms(p, cfg):
     return n1, n2
 
 
+# Named scopes (``attn``, ``ffn``, ``kv_update``, ``weight_cast``, ``embed``,
+# ``final_norm``, ``head``) label the compiled operations of each layer
+# part in the program's op metadata and so in a device trace.
+
+
 def layer_fwd(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     n1, n2 = _norms(p, cfg)
     x = constrain(x, "residual")
-    x = x + attention(p["attn"], n1(x), cfg)
-    x = x + ffn(p["ffn"], n2(x), cfg)
+    with jax.named_scope("attn"):
+        x = x + attention(p["attn"], n1(x), cfg)
+    with jax.named_scope("ffn"):
+        x = x + ffn(p["ffn"], n2(x), cfg)
     return constrain(x, "residual")
 
 
 def layer_prefill(p: dict, x: jax.Array, cfg: ModelConfig, max_len: int = 0):
     n1, n2 = _norms(p, cfg)
-    a, cache = prefill_attention(p["attn"], n1(x), cfg, max_len=max_len)
-    x = x + a
-    x = x + ffn(p["ffn"], n2(x), cfg)
+    with jax.named_scope("attn"):
+        a, cache = prefill_attention(p["attn"], n1(x), cfg, max_len=max_len)
+        x = x + a
+    with jax.named_scope("ffn"):
+        x = x + ffn(p["ffn"], n2(x), cfg)
     return x, cache
 
 
 def layer_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array,
                  cfg: ModelConfig):
     n1, n2 = _norms(p, cfg)
-    a, cache = decode_attention(p["attn"], n1(x), cache, pos, cfg)
-    x = x + a
-    x = x + ffn(p["ffn"], n2(x), cfg)
+    with jax.named_scope("attn"):
+        a, cache = decode_attention(p["attn"], n1(x), cache, pos, cfg)
+        x = x + a
+    with jax.named_scope("ffn"):
+        x = x + ffn(p["ffn"], n2(x), cfg)
     return x, cache
 
 
@@ -108,29 +119,43 @@ class DecoderLM:
         return p
 
     # ---- embedding / head ----------------------------------------------------
+    def _cast(self, tree):
+        """The stored weights in ``tree`` as compute-dtype copies."""
+        with jax.named_scope("weight_cast"):
+            return jax.tree.map(lambda a: a.astype(self.dtype)
+                                if a.dtype == self.pdtype else a, tree)
+
+    def _embed(self, params, tokens) -> jax.Array:
+        with jax.named_scope("weight_cast"):
+            table = params["embed"].astype(self.dtype)
+        with jax.named_scope("embed"):
+            return jnp.take(table, tokens, axis=0)
+
     def _embed_tokens(self, params, batch) -> jax.Array:
-        x = constrain(jnp.take(params["embed"].astype(self.dtype),
-                               batch["tokens"], axis=0), "residual")
+        x = constrain(self._embed(params, batch["tokens"]), "residual")
         if self.cfg.family == "vlm" and "patch_embeds" in batch:
             # anyres frontend stub: precomputed patch embeddings are prefixed
             x = jnp.concatenate(
                 [batch["patch_embeds"].astype(self.dtype), x], axis=1)
         return x
 
+    def _final_norm(self, params, x) -> jax.Array:
+        with jax.named_scope("final_norm"):
+            return norm_fn("rmsnorm")(x, params["norm_f"])
+
     def _head(self, params, x) -> jax.Array:
-        w = (params["embed"].T if self.cfg.tie_embeddings
-             else params["lm_head"]).astype(self.dtype)
-        return constrain(jnp.dot(x, w), "logits")
+        w = params["embed"].T if self.cfg.tie_embeddings else params["lm_head"]
+        with jax.named_scope("weight_cast"):
+            w = w.astype(self.dtype)
+        with jax.named_scope("head"):
+            return constrain(jnp.dot(x, w), "logits")
 
     # ---- scanned layer stack ---------------------------------------------------
     def _run_layers(self, params, x) -> jax.Array:
         cfg = self.cfg
-        cast = functools.partial(jax.tree.map,
-                                 lambda a: a.astype(self.dtype)
-                                 if a.dtype == self.pdtype else a)
 
         def body(h, layer_p):
-            return layer_fwd(cast(layer_p), h, cfg), None
+            return layer_fwd(self._cast(layer_p), h, cfg), None
 
         if cfg.remat:
             body = jax.checkpoint(body)
@@ -140,8 +165,7 @@ class DecoderLM:
     def logits(self, params, batch) -> jax.Array:
         x = self._embed_tokens(params, batch)
         x = self._run_layers(params, x)
-        x = norm_fn("rmsnorm")(x, params["norm_f"])
-        return self._head(params, x)
+        return self._head(params, self._final_norm(params, x))
 
     def loss(self, params, batch) -> jax.Array:
         logits = self.logits(params, batch)
@@ -160,35 +184,29 @@ class DecoderLM:
     def prefill(self, params, batch, max_len: int = 0):
         cfg = self.cfg
         x = self._embed_tokens(params, batch)
-        cast = functools.partial(jax.tree.map,
-                                 lambda a: a.astype(self.dtype)
-                                 if a.dtype == self.pdtype else a)
 
         def body(h, layer_p):
-            h2, cache = layer_prefill(cast(layer_p), h, cfg, max_len=max_len)
+            h2, cache = layer_prefill(self._cast(layer_p), h, cfg,
+                                      max_len=max_len)
             return h2, cache
 
         if cfg.remat:
             body = jax.checkpoint(body)
         x, caches = jax.lax.scan(body, x, params["layers"])
-        x = norm_fn("rmsnorm")(x, params["norm_f"])
+        x = self._final_norm(params, x)
         return {"kv": caches}, self._head(params, x[:, -1:])
 
     def decode_step(self, params, cache, tokens, pos):
         """tokens (B,) int32; pos scalar int32 absolute position."""
         cfg = self.cfg
-        x = jnp.take(params["embed"].astype(self.dtype), tokens[:, None],
-                     axis=0)
-        cast = functools.partial(jax.tree.map,
-                                 lambda a: a.astype(self.dtype)
-                                 if a.dtype == self.pdtype else a)
+        x = self._embed(params, tokens[:, None])
 
         def body(h, xs):
             layer_p, layer_cache = xs
-            h2, new_cache = layer_decode(cast(layer_p), h, layer_cache, pos,
-                                         cfg)
+            h2, new_cache = layer_decode(self._cast(layer_p), h, layer_cache,
+                                         pos, cfg)
             return h2, new_cache
 
         x, new_caches = jax.lax.scan(body, x, (params["layers"], cache["kv"]))
-        x = norm_fn("rmsnorm")(x, params["norm_f"])
+        x = self._final_norm(params, x)
         return self._head(params, x)[:, 0], {"kv": new_caches}
