@@ -34,7 +34,8 @@ def generate(model, params, batch, max_new: int, greedy: bool = True,
 
     Records the spans ``generate`` (the root), ``generate.prefill``,
     ``generate.decode`` and one ``generate.decode_step`` per step
-    (``repro.runtime.spans``)."""
+    (``repro.runtime.spans``).  Nothing here waits for the device, so
+    once the model's steps are compiled the spans time their dispatch."""
     cfg = model.cfg
     tokens = batch["tokens"]
     B, T = tokens.shape

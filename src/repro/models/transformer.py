@@ -3,6 +3,11 @@
 Layers are parameter-stacked and driven by ``jax.lax.scan`` so compile time
 and HLO size are O(1) in depth — essential for the 512-device dry-runs.
 Remat (``jax.checkpoint``) wraps the scanned body when cfg.remat is set.
+
+``DecoderLM.prefill`` and ``decode_step``, called outside any JAX trace and
+activation sharding context, run a ``jax.jit`` of their implementation that
+each model instance compiles once per input shape; under an outer trace or
+inside a sharding context they trace their implementation inline.
 """
 from __future__ import annotations
 
@@ -11,7 +16,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..dist.ctx import constrain
+from ..dist.ctx import constrain, current_rules
 from .attention import (attention, decode_attention, init_attn_params,
                         init_kv_cache, prefill_attention)
 from .config import ModelConfig
@@ -91,6 +96,14 @@ def layer_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array,
     return x, cache
 
 
+def _dispatch_now() -> bool:
+    """True outside any JAX trace (jit, vmap, grad, eval_shape) and any
+    activation sharding context: the call runs now on concrete arrays, at
+    their own placement, so a compiled entry point, whose cache sees
+    neither an outer trace nor the context, may serve it."""
+    return current_rules() is None and jax.core.trace_ctx.is_top_level()
+
+
 class DecoderLM:
     """Families: dense (olmo/qwen*), moe (mixtral/phi3.5-moe), vlm (llava)."""
 
@@ -98,6 +111,9 @@ class DecoderLM:
         self.cfg = cfg
         self.dtype = jnp.dtype(cfg.dtype)
         self.pdtype = jnp.dtype(cfg.param_dtype)
+        # serving entry points, compiled once per instance and input shape
+        self._prefill_jit = jax.jit(self._prefill, static_argnames="max_len")
+        self._decode_jit = jax.jit(self._decode_step, donate_argnames="cache")
 
     # ---- parameters -------------------------------------------------------
     def init(self, rng) -> dict:
@@ -182,6 +198,21 @@ class DecoderLM:
             one)}
 
     def prefill(self, params, batch, max_len: int = 0):
+        """(cache sized for ``max_len`` positions, last position's logits);
+        compiled per shape when ``_dispatch_now``, else traced inline."""
+        if _dispatch_now():
+            return self._prefill_jit(params, batch, max_len=max_len)
+        return self._prefill(params, batch, max_len=max_len)
+
+    def decode_step(self, params, cache, tokens, pos):
+        """tokens (B,) int32; pos scalar int32 absolute position.  Compiled
+        once per shape when ``_dispatch_now``, with ``cache`` donated (its
+        arrays are deleted by the call); else traced inline."""
+        if _dispatch_now():
+            return self._decode_jit(params, cache, tokens, pos)
+        return self._decode_step(params, cache, tokens, pos)
+
+    def _prefill(self, params, batch, max_len: int = 0):
         cfg = self.cfg
         x = self._embed_tokens(params, batch)
 
@@ -196,8 +227,7 @@ class DecoderLM:
         x = self._final_norm(params, x)
         return {"kv": caches}, self._head(params, x[:, -1:])
 
-    def decode_step(self, params, cache, tokens, pos):
-        """tokens (B,) int32; pos scalar int32 absolute position."""
+    def _decode_step(self, params, cache, tokens, pos):
         cfg = self.cfg
         x = self._embed(params, tokens[:, None])
 

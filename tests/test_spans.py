@@ -129,11 +129,11 @@ def test_generate_spans(olmo):
     steps = by["generate.decode_step"]
     assert [r.attrs["step"] for r in steps] == list(range(1, max_new))
     assert all(r.parent == decode.id for r in steps)
-    # the eager decode step traces and lowers its layer scan on every call;
-    # a jitted decode step would lower nothing here
-    lowerings = spans.inclusive(recs)[decode.id]["lowerings"]
-    assert lowerings >= decode.attrs["steps"]
-    assert spans.inclusive(recs)[by["generate.prefill"][0].id]["lowerings"] >= 1
+    # the prefill and the decode step were compiled by the warm call: the
+    # steady state lowers nothing
+    inc = spans.inclusive(recs)
+    assert inc[decode.id]["lowerings"] == 0
+    assert inc[by["generate.prefill"][0].id]["lowerings"] == 0
 
 
 def test_generate_spans_in_profiler_trace(olmo, tmp_path):
@@ -222,4 +222,6 @@ def test_serve_main_prints_span_summary(monkeypatch, capsys):
     assert s["generate"]["count"] == 1
     assert s["generate.decode"]["count"] == 1
     assert s["generate.decode_step"]["count"] == 2
-    assert s["generate.decode"]["counters"]["lowerings"] >= 2
+    # cold: each compiled entry point lowers once, at its first call
+    assert s["generate.prefill"]["counters"].get("lowerings", 0) <= 1
+    assert s["generate.decode"]["counters"].get("lowerings", 0) <= 1

@@ -102,6 +102,24 @@ def test_olmo_decode_step_fits_one_v5e(one_chip):
     assert total < V5E_HBM_BYTES, total
 
 
+def test_olmo_served_decode_step_donates_its_cache(one_chip):
+    """The decode step that ``generate`` dispatches (``DecoderLM``'s compiled
+    entry point) at the batch-decode cell's size, 32 x 544: its output cache
+    takes the donated cache's buffer, and the step fits one chip's HBM."""
+    cfg = get_config("olmo-1b")
+    model, params = eval_shape_params(cfg)
+    cache = eval_shape_cache(cfg, 32, 544)
+    args = shapes(one_chip, (params, cache,
+                             jax.ShapeDtypeStruct((32,), jnp.int32),
+                             jax.ShapeDtypeStruct((), jnp.int32)))
+    mem = model._decode_jit.lower(*args).compile().memory_analysis()
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes == cache_bytes
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < V5E_HBM_BYTES, total
+
+
 def test_olmo_train_step_fits_four_v5e(topo):
     """olmo-1b training at full width, tensor-parallel over a (data=1,
     model=4) mesh with the rules ``launch/train.py:build_trainer`` uses: the
