@@ -26,7 +26,8 @@ if str(ROOT / "src") not in sys.path:
 
 #: named scopes of the model and the kernels (``models/``, ``kernels/``)
 SCOPES = ("embed", "weight_cast", "attn", "kv_update", "ffn", "final_norm",
-          "head", "isam_gemm.pad", "isam_gemm.crop", "isam_gemm",
+          "head", "mla.latent", "mla.absorb", "mla.attend", "moe.route",
+          "moe.experts", "moe.shared", "isam_gemm.pad", "isam_gemm.crop", "isam_gemm",
           "isam_gemm_bias_act.pad", "isam_gemm_bias_act.crop",
           "isam_gemm_bias_act", "isam_gru_cell")
 BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,

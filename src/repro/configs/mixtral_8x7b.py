@@ -14,5 +14,5 @@ CONFIG = ModelConfig(
 
 def smoke_config() -> ModelConfig:
     return CONFIG.scaled(n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
-                         d_ff=96, vocab_size=128, n_experts=4, top_k=2, capacity_factor=8.0, 
+                         d_ff=96, vocab_size=128, n_experts=4, top_k=2,
                          sliding_window=8, remat=False)

@@ -23,6 +23,7 @@ ARCHS = [
     "llava-next-34b",
     "jamba-1.5-large-398b",
     "whisper-medium",
+    "deepseek-v2-lite",
 ]
 
 

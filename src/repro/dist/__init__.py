@@ -2,7 +2,7 @@
 
 The models stay mesh-agnostic: they call ``ctx.constrain(x, name)`` with a
 small rule-name vocabulary (``residual``, ``heads``, ``tokens``,
-``ffn_hidden``, ``logits``, ``scores``, ``expert_*``, ``kv/*``) and the
+``ffn_hidden``, ``logits``, ``scores``, ``kv/*``) and the
 launch layer decides what those names mean for the mesh at hand by entering
 ``ctx.activation_sharding_ctx(sharding.make_activation_rules(mesh, cfg))``.
 Outside the context every constraint is a transparent no-op, so kernels and

@@ -128,8 +128,9 @@ def param_spec(name: str, shape: tuple[int, ...], mesh, cfg) -> P:
 
     # MoE expert weights: (..., E, in, out)
     if (leaf in _EXPERT_LEAVES and ndim >= 3 and getattr(cfg, "n_experts", 0)
-            and shape[-3] == cfg.n_experts and "ffn" in name.split("/")):
-        ep = shard_dim(mesh, cfg.n_experts, MODEL_AXIS)
+            and shape[-3] == cfg.n_held and "ffn" in name.split("/")
+            and "shared" not in name.split("/")):
+        ep = shard_dim(mesh, cfg.n_held, MODEL_AXIS)
         if ep is not None:              # expert-parallel over the model axis
             entries[-3] = _one(ep)
             entries[-2] = _one(shard_dim(mesh, shape[-2], dp))
@@ -220,6 +221,10 @@ def cache_spec(name: str, shape: tuple[int, ...], mesh, cfg) -> P:
             entries[-1] = _one(shard_dim(mesh, shape[-1], MODEL_AXIS))
         return _spec(entries)
 
+    if leaf in ("latent", "k_rope") and ndim >= 3:   # MLA cache (..., B, S, r)
+        entries[ndim - 3] = _one(shard_dim(mesh, shape[ndim - 3], dp))
+        return _spec(entries)
+
     if leaf == "h" and ndim >= 3:           # mamba SSM state (..., B, di, ds)
         entries[ndim - 3] = _one(shard_dim(mesh, shape[ndim - 3], dp))
         entries[-2] = _one(shard_dim(mesh, shape[-2], MODEL_AXIS))
@@ -284,21 +289,6 @@ def make_activation_rules(mesh, cfg):
             entries[1] = _one(shard_dim(mesh, shape[1], MODEL_AXIS))
         return entries
 
-    def _expert_tokens(shape):
-        # (E, G, C, D): expert-parallel over model when E divides
-        entries = [None] * len(shape)
-        entries[0] = _one(shard_dim(mesh, shape[0], MODEL_AXIS))
-        if len(shape) >= 2:
-            entries[1] = _one(shard_dim(mesh, shape[1], dp))
-        return entries
-
-    def _expert_hidden(shape):
-        # (E, G, C, F): EP on E, else TP on the expert-hidden dim
-        entries = _expert_tokens(shape)
-        if entries[0] is None:
-            entries[-1] = _one(shard_dim(mesh, shape[-1], MODEL_AXIS))
-        return entries
-
     builders = {
         "residual": _batchish,
         "tokens": _batchish,
@@ -306,8 +296,6 @@ def make_activation_rules(mesh, cfg):
         "scores": _scores,
         "ffn_hidden": _last_model,
         "logits": _last_model,
-        "expert_tokens4": _expert_tokens,
-        "expert_hidden4": _expert_hidden,
     }
 
     def rules(name: str, shape):

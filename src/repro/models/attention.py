@@ -58,16 +58,20 @@ def _expand_kv(k: jax.Array, n_heads: int) -> jax.Array:
 ATTN_CHUNK = 2048
 
 
-def _attend(q, k, v, positions, cfg: ModelConfig, causal: bool) -> jax.Array:
-    """Softmax attention on projected/rotated q, k, v (B, T|S, H, hd).
-    Long sequences are processed in query chunks (lax.scan): exact softmax
-    per row, activation memory O(T * chunk) instead of O(T^2)."""
+def _attend(q, k, v, positions, cfg: ModelConfig, causal: bool,
+            scale: float | None = None, chunk: int = ATTN_CHUNK) -> jax.Array:
+    """Softmax attention on projected/rotated q, k, v (B, T|S, H, hd); v
+    may have its own head dim.  Scores are scaled by ``scale``, or divided
+    by sqrt(hd).  Sequences longer than ``chunk`` are processed in query
+    chunks (lax.scan): exact softmax per row, activation memory
+    O(T * chunk) instead of O(T^2)."""
     B, T, H, hd = q.shape
     S = k.shape[1]
+    dv = v.shape[-1]
 
     def block(q_blk, pos_blk):
-        scores = constrain(jnp.einsum("bthd,bshd->bhts", q_blk, k),
-                           "scores") / (hd ** 0.5)
+        scores = constrain(jnp.einsum("bthd,bshd->bhts", q_blk, k), "scores")
+        scores = scores / (hd ** 0.5) if scale is None else scores * scale
         if causal:
             i = pos_blk[:, None]
             j = positions[None, :S] if positions.shape[0] >= S \
@@ -80,19 +84,19 @@ def _attend(q, k, v, positions, cfg: ModelConfig, causal: bool) -> jax.Array:
                            ).astype(q_blk.dtype)
         return jnp.einsum("bhts,bshd->bthd", w, v)
 
-    if T <= ATTN_CHUNK or T % ATTN_CHUNK:
+    if T <= chunk or T % chunk:
         return block(q, positions)
 
-    nc = T // ATTN_CHUNK
-    qc = jnp.moveaxis(q.reshape(B, nc, ATTN_CHUNK, H, hd), 1, 0)
-    pc = positions.reshape(nc, ATTN_CHUNK)
+    nc = T // chunk
+    qc = jnp.moveaxis(q.reshape(B, nc, chunk, H, hd), 1, 0)
+    pc = positions.reshape(nc, chunk)
 
     def body(_, xs):
         qb, pb = xs
         return None, block(qb, pb)
 
-    _, outs = jax.lax.scan(body, None, (qc, pc))      # (nc, B, c, H, hd)
-    return jnp.moveaxis(outs, 0, 1).reshape(B, T, H, hd)
+    _, outs = jax.lax.scan(body, None, (qc, pc))      # (nc, B, c, H, dv)
+    return jnp.moveaxis(outs, 0, 1).reshape(B, T, H, dv)
 
 
 def attention(p: dict, x: jax.Array, cfg: ModelConfig,

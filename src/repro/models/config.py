@@ -22,11 +22,31 @@ class ModelConfig:
     tie_embeddings: bool = False
     rope_theta: float = 1e4
     sliding_window: int = 0     # >0: SWA (mixtral)
-    # MoE
+    # YaRN rope scaling (deepseek-v2): factor 0 = plain rope
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+    # multi-head latent attention (deepseek-v2): kv_lora_rank > 0 selects it
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # MoE: a softmax router over n_experts, greedy top_k, dropless
     n_experts: int = 0
     top_k: int = 0
-    capacity_factor: float = 1.25
+    norm_topk_prob: bool = True     # renormalise the top-k weights
+    routed_scaling: float = 1.0
+    moe_d_ff: int = 0               # expert width; 0 -> d_ff
+    n_shared_experts: int = 0       # one SwiGLU of width n * moe_d_ff
+    first_dense_layers: int = 0     # leading layers with the dense d_ff FFN
     moe_period: int = 1         # MoE FFN every Nth layer (jamba: 2)
+    # the experts this chip holds: held_experts from first_held_expert
+    # (0 -> all); the router still scores all n_experts
+    held_experts: int = 0
+    first_held_expert: int = 0
     # hybrid (jamba): one attention layer per `attn_period`, rest mamba
     attn_period: int = 0
     mamba_d_state: int = 16
@@ -50,6 +70,14 @@ class ModelConfig:
         return self.head_dim or self.d_model // self.n_heads
 
     @property
+    def expert_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def n_held(self) -> int:
+        return self.held_experts or self.n_experts
+
+    @property
     def activation_dtype(self):
         return jnp.dtype(self.dtype)
 
@@ -57,12 +85,23 @@ class ModelConfig:
         return replace(self, **kw)
 
     # ---- parameter count (for MODEL_FLOPS = 6 N D) -------------------------
-    def param_count(self, active_only: bool = False) -> int:
-        D, H, KV, hd, F, V = (self.d_model, self.n_heads, self.n_kv_heads,
-                              self.hd, self.d_ff, self.vocab_size)
+    def _attn_params(self) -> int:
+        D, H, KV, hd = self.d_model, self.n_heads, self.n_kv_heads, self.hd
+        if self.kv_lora_rank:
+            r, qk = self.kv_lora_rank, self.qk_nope_head_dim + self.qk_rope_head_dim
+            return (D * H * qk + D * (r + self.qk_rope_head_dim) + r
+                    + r * H * (self.qk_nope_head_dim + self.v_head_dim)
+                    + H * self.v_head_dim * D)
         attn = D * H * hd + 2 * D * KV * hd + H * hd * D
         if self.qkv_bias:
             attn += (H + 2 * KV) * hd
+        return attn
+
+    def param_count(self, active_only: bool = False) -> int:
+        """Parameters of the published model: every expert, whatever
+        ``held_experts`` says (``active_only``: the top_k routed ones)."""
+        D, F, V = self.d_model, self.d_ff, self.vocab_size
+        attn = self._attn_params()
         mlp_dense = 3 * D * F                    # swiglu gate/up/down
         if self.family == "hybrid" and self.attn_period:
             n_attn_layers = self.n_layers // self.attn_period
@@ -81,12 +120,14 @@ class ModelConfig:
         else:
             total = self.n_layers * attn
         if self.family != "ssm":
-            n_moe = self.n_layers // self.moe_period if self.n_experts else 0
+            n_moe = ((self.n_layers - self.first_dense_layers) // self.moe_period
+                     if self.n_experts else 0)
             n_dense = self.n_layers - n_moe
             if n_moe:
-                experts = self.n_experts * mlp_dense + D * self.n_experts
-                active = self.top_k * mlp_dense + D * self.n_experts
-                total += n_moe * (active if active_only else experts)
+                expert = 3 * D * self.expert_ff
+                routed = self.top_k if active_only else self.n_experts
+                total += n_moe * ((routed + self.n_shared_experts) * expert
+                                  + D * self.n_experts)          # + router
             total += n_dense * mlp_dense
         total += 2 * D  # final norm(s)
         total += V * D * (1 if self.tie_embeddings else 2)
@@ -119,7 +160,7 @@ SHAPES = {
 #: archs with quadratic full attention skip long_500k (see DESIGN.md)
 FULL_ATTENTION_ARCHS = {
     "olmo-1b", "qwen2-7b", "qwen1.5-32b", "qwen2.5-32b", "llava-next-34b",
-    "whisper-medium",
+    "whisper-medium", "deepseek-v2-lite",
 }
 
 

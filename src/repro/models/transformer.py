@@ -3,6 +3,10 @@
 Layers are parameter-stacked and driven by ``jax.lax.scan`` so compile time
 and HLO size are O(1) in depth — essential for the 512-device dry-runs.
 Remat (``jax.checkpoint``) wraps the scanned body when cfg.remat is set.
+Leading dense layers (``first_dense_layers``, DeepSeek-V2's layer 0) are a
+second stacked group, scanned before the main stack, with their own cache.
+Attention is GQA/MHA (``attention.py``) or, when ``kv_lora_rank`` is set,
+multi-head latent attention (``mla.py``).
 
 ``DecoderLM.prefill`` and ``decode_step``, called outside any JAX trace and
 activation sharding context, run a ``jax.jit`` of their implementation that
@@ -17,8 +21,8 @@ import jax
 import jax.numpy as jnp
 
 from ..dist.ctx import constrain, current_rules
-from .attention import (attention, decode_attention, init_attn_params,
-                        init_kv_cache, prefill_attention)
+from . import attention as gqa
+from . import mla
 from .config import ModelConfig
 from .layers import cross_entropy_loss, init_dense, norm_fn
 from .moe import init_moe_params, moe_ffn
@@ -43,9 +47,14 @@ def ffn(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     return constrain(jnp.dot(h, p["w_down"]), "residual")
 
 
+def _attn(cfg: ModelConfig):
+    """The layer's attention module: GQA/MHA, or multi-head latent."""
+    return mla if cfg.kv_lora_rank else gqa
+
+
 def init_layer_params(rng, cfg: ModelConfig, dtype) -> dict:
     k1, k2 = jax.random.split(rng)
-    p = {"attn": init_attn_params(k1, cfg, dtype),
+    p = {"attn": _attn(cfg).init_attn_params(k1, cfg, dtype),
          "ffn": init_ffn_params(k2, cfg, dtype)}
     if cfg.norm == "rmsnorm":
         p["norm1"] = jnp.ones((cfg.d_model,), jnp.float32)
@@ -69,7 +78,7 @@ def layer_fwd(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     n1, n2 = _norms(p, cfg)
     x = constrain(x, "residual")
     with jax.named_scope("attn"):
-        x = x + attention(p["attn"], n1(x), cfg)
+        x = x + _attn(cfg).attention(p["attn"], n1(x), cfg)
     with jax.named_scope("ffn"):
         x = x + ffn(p["ffn"], n2(x), cfg)
     return constrain(x, "residual")
@@ -78,7 +87,8 @@ def layer_fwd(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
 def layer_prefill(p: dict, x: jax.Array, cfg: ModelConfig, max_len: int = 0):
     n1, n2 = _norms(p, cfg)
     with jax.named_scope("attn"):
-        a, cache = prefill_attention(p["attn"], n1(x), cfg, max_len=max_len)
+        a, cache = _attn(cfg).prefill_attention(p["attn"], n1(x), cfg,
+                                                  max_len=max_len)
         x = x + a
     with jax.named_scope("ffn"):
         x = x + ffn(p["ffn"], n2(x), cfg)
@@ -89,7 +99,8 @@ def layer_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array,
                  cfg: ModelConfig):
     n1, n2 = _norms(p, cfg)
     with jax.named_scope("attn"):
-        a, cache = decode_attention(p["attn"], n1(x), cache, pos, cfg)
+        a, cache = _attn(cfg).decode_attention(p["attn"], n1(x), cache, pos,
+                                                cfg)
         x = x + a
     with jax.named_scope("ffn"):
         x = x + ffn(p["ffn"], n2(x), cfg)
@@ -105,10 +116,17 @@ def _dispatch_now() -> bool:
 
 
 class DecoderLM:
-    """Families: dense (olmo/qwen*), moe (mixtral/phi3.5-moe), vlm (llava)."""
+    """Families: dense (olmo/qwen*), moe (mixtral/phi3.5-moe/deepseek-v2),
+    vlm (llava)."""
 
     def __init__(self, cfg: ModelConfig):
         self.cfg = cfg
+        # (params key, cache key, config, layers) of each stacked group
+        self.groups = [("layers", "kv", cfg, cfg.n_layers - cfg.first_dense_layers)]
+        if cfg.first_dense_layers:
+            self.groups.insert(0, ("dense_layers", "kv_dense",
+                                   cfg.scaled(n_experts=0),
+                                   cfg.first_dense_layers))
         self.dtype = jnp.dtype(cfg.dtype)
         self.pdtype = jnp.dtype(cfg.param_dtype)
         # serving entry points, compiled once per instance and input shape
@@ -119,16 +137,15 @@ class DecoderLM:
     def init(self, rng) -> dict:
         cfg = self.cfg
         ks = jax.random.split(rng, 4)
-        layer_keys = jax.random.split(ks[0], cfg.n_layers)
-        layers = jax.vmap(
-            lambda k: init_layer_params(k, cfg, self.pdtype))(layer_keys)
         p = {
             "embed": (jax.random.normal(
                 ks[1], (cfg.vocab_size, cfg.d_model), jnp.float32)
                 * 0.02).astype(self.pdtype),
-            "layers": layers,
             "norm_f": jnp.ones((cfg.d_model,), jnp.float32),
         }
+        for (key, _, gcfg, n), k in zip(self.groups[::-1], (ks[0], ks[3])):
+            p[key] = jax.vmap(lambda k, gcfg=gcfg: init_layer_params(
+                k, gcfg, self.pdtype))(jax.random.split(k, n))
         if not cfg.tie_embeddings:
             p["lm_head"] = init_dense(ks[2], cfg.d_model, cfg.vocab_size,
                                       self.pdtype)
@@ -168,14 +185,13 @@ class DecoderLM:
 
     # ---- scanned layer stack ---------------------------------------------------
     def _run_layers(self, params, x) -> jax.Array:
-        cfg = self.cfg
+        for key, _, cfg, _ in self.groups:
+            def body(h, layer_p, cfg=cfg):
+                return layer_fwd(self._cast(layer_p), h, cfg), None
 
-        def body(h, layer_p):
-            return layer_fwd(self._cast(layer_p), h, cfg), None
-
-        if cfg.remat:
-            body = jax.checkpoint(body)
-        x, _ = jax.lax.scan(body, x, params["layers"])
+            if cfg.remat:
+                body = jax.checkpoint(body)
+            x, _ = jax.lax.scan(body, x, params[key])
         return x
 
     def logits(self, params, batch) -> jax.Array:
@@ -191,11 +207,12 @@ class DecoderLM:
 
     # ---- serving ----------------------------------------------------------------
     def init_cache(self, batch: int, seq_len: int) -> dict:
-        cfg = self.cfg
-        one = init_kv_cache(cfg, batch, seq_len, self.dtype)
-        return {"kv": jax.tree.map(
-            lambda a: jnp.broadcast_to(a[None], (cfg.n_layers,) + a.shape),
-            one)}
+        cache = {}
+        for _, key, cfg, n in self.groups:
+            one = _attn(cfg).init_kv_cache(cfg, batch, seq_len, self.dtype)
+            cache[key] = jax.tree.map(
+                lambda a, n=n: jnp.broadcast_to(a[None], (n,) + a.shape), one)
+        return cache
 
     def prefill(self, params, batch, max_len: int = 0):
         """(cache sized for ``max_len`` positions, last position's logits);
@@ -212,31 +229,46 @@ class DecoderLM:
             return self._decode_jit(params, cache, tokens, pos)
         return self._decode_step(params, cache, tokens, pos)
 
+    def lower_serving(self, params, batch, max_len: int):
+        """The programs that ``prefill`` and ``decode_step`` dispatch to for
+        ``batch``'s shape and a cache of ``max_len`` positions, lowered
+        (``jax.stages.Lowered``: compile one to read its HLO or its
+        memory); arguments may be ``jax.ShapeDtypeStruct``s."""
+        prefill = self._prefill_jit.lower(params, batch, max_len=max_len)
+        cache = jax.eval_shape(
+            lambda p, b: self._prefill(p, b, max_len=max_len)[0], params, batch)
+        B = batch["tokens"].shape[0]
+        decode = self._decode_jit.lower(params, cache,
+                                        jax.ShapeDtypeStruct((B,), jnp.int32),
+                                        jax.ShapeDtypeStruct((), jnp.int32))
+        return prefill, decode
+
     def _prefill(self, params, batch, max_len: int = 0):
-        cfg = self.cfg
         x = self._embed_tokens(params, batch)
+        caches = {}
+        for key, ckey, cfg, _ in self.groups:
+            def body(h, layer_p, cfg=cfg):
+                h2, cache = layer_prefill(self._cast(layer_p), h, cfg,
+                                          max_len=max_len)
+                return h2, cache
 
-        def body(h, layer_p):
-            h2, cache = layer_prefill(self._cast(layer_p), h, cfg,
-                                      max_len=max_len)
-            return h2, cache
-
-        if cfg.remat:
-            body = jax.checkpoint(body)
-        x, caches = jax.lax.scan(body, x, params["layers"])
+            if cfg.remat:
+                body = jax.checkpoint(body)
+            x, caches[ckey] = jax.lax.scan(body, x, params[key])
         x = self._final_norm(params, x)
-        return {"kv": caches}, self._head(params, x[:, -1:])
+        return caches, self._head(params, x[:, -1:])
 
     def _decode_step(self, params, cache, tokens, pos):
-        cfg = self.cfg
         x = self._embed(params, tokens[:, None])
+        new_caches = {}
+        for key, ckey, cfg, _ in self.groups:
+            def body(h, xs, cfg=cfg):
+                layer_p, layer_cache = xs
+                h2, new_cache = layer_decode(self._cast(layer_p), h,
+                                             layer_cache, pos, cfg)
+                return h2, new_cache
 
-        def body(h, xs):
-            layer_p, layer_cache = xs
-            h2, new_cache = layer_decode(self._cast(layer_p), h, layer_cache,
-                                         pos, cfg)
-            return h2, new_cache
-
-        x, new_caches = jax.lax.scan(body, x, (params["layers"], cache["kv"]))
+            x, new_caches[ckey] = jax.lax.scan(body, x,
+                                               (params[key], cache[ckey]))
         x = self._final_norm(params, x)
-        return self._head(params, x)[:, 0], {"kv": new_caches}
+        return self._head(params, x)[:, 0], new_caches

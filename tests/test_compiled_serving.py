@@ -118,3 +118,22 @@ def test_traced_or_placed_calls_trace_inline(arch, caller, monkeypatch):
     else:
         assert taken == []
         _close(got, ((want_cache, want), want_step))
+
+
+@pytest.mark.parametrize("arch", ARCHS + ["deepseek-v2-lite"])
+def test_lower_serving_gives_the_dispatched_programs(arch):
+    """``lower_serving`` lowers the programs that ``prefill`` and
+    ``decode_step`` run: compiled, they give the served cache, logits and
+    step bit for bit, under the entry points' module names."""
+    model, params, batch, max_len, pos = _setup(arch)
+    pre, dec = (p.compile() for p in model.lower_serving(params, batch, max_len))
+    assert pre.as_text().startswith("HloModule jit__prefill,")
+    assert dec.as_text().startswith("HloModule jit__decode_step,")
+    want_cache, want = model.prefill(params, batch, max_len=max_len)
+    cache, logits = pre(params, batch)
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(want))
+    tok = jnp.argmax(want[:, -1], axis=-1).astype(jnp.int32)
+    got = dec(params, cache, tok, pos)
+    want_step = model.decode_step(params, want_cache, tok, pos)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want_step), strict=True):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

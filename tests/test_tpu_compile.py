@@ -120,6 +120,30 @@ def test_olmo_served_decode_step_donates_its_cache(one_chip):
     assert total < V5E_HBM_BYTES, total
 
 
+def test_deepseek_served_decode_step_fits_and_donates_its_cache(one_chip):
+    """DeepSeek-V2-Lite's served decode step at the long-decode cell's
+    size (the leading dense layer and 4 MoE layers holding 16 of 64
+    experts, 32 x 4224 positions): the latent cache of both layer groups
+    takes the donated buffers, the grouped expert matmul compiles for the
+    chip, and the step fits one chip's HBM."""
+    cfg = get_config("deepseek-v2-lite").scaled(n_layers=5, held_experts=16)
+    model, params = eval_shape_params(cfg)
+    cache = eval_shape_cache(cfg, 32, 4224)
+    assert set(cache) == {"kv", "kv_dense"}
+    args = shapes(one_chip, (params, cache,
+                             jax.ShapeDtypeStruct((32,), jnp.int32),
+                             jax.ShapeDtypeStruct((), jnp.int32)))
+    compiled = model._decode_jit.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert cache_bytes == 32 * 4224 * 5 * (512 + 64) * 2
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert "ragged" in compiled.as_text()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < V5E_HBM_BYTES, total
+
+
 def test_olmo_train_step_fits_four_v5e(topo):
     """olmo-1b training at full width, tensor-parallel over a (data=1,
     model=4) mesh with the rules ``launch/train.py:build_trainer`` uses: the
