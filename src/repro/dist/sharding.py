@@ -191,9 +191,10 @@ def batch_spec(name: str, shape: tuple[int, ...], mesh) -> P:
 def cache_spec(name: str, shape: tuple[int, ...], mesh, cfg) -> P:
     """Placement for decode-state leaves.
 
-    * ``kv/{k,v}`` (..., B, S, KV, hd): batch over data; KV heads over
-      ``model`` when they divide, else the head_dim takes ``model`` (GQA
-      archs like qwen2.5's kv=8 on a 16-way axis);
+    * ``kv/{k,v}`` (..., S, KV, B, hd), the attention cache order: batch
+      over data; KV heads over ``model`` when they divide, else the
+      head_dim takes ``model`` (GQA archs like qwen2.5's kv=8 on a 16-way
+      axis);
     * mamba ``h``/``conv``: batch over data, d_inner over ``model``;
     * mLSTM/sLSTM recurrent state: batch over data, trailing feature dim
       over ``model`` when divisible.
@@ -212,11 +213,11 @@ def cache_spec(name: str, shape: tuple[int, ...], mesh, cfg) -> P:
             entries[-1] = _one(shard_dim(mesh, shape[-1], MODEL_AXIS))
         return _spec(entries)
 
-    if leaf in ("k", "v") and ndim >= 4:    # KV cache
-        entries[ndim - 4] = _one(shard_dim(mesh, shape[ndim - 4], dp))
-        heads = shard_dim(mesh, shape[-2], MODEL_AXIS)
+    if leaf in ("k", "v") and ndim >= 4:    # KV cache (..., S, KV, B, hd)
+        entries[-2] = _one(shard_dim(mesh, shape[-2], dp))
+        heads = shard_dim(mesh, shape[-3], MODEL_AXIS)
         if heads is not None:
-            entries[-2] = _one(heads)
+            entries[-3] = _one(heads)
         else:
             entries[-1] = _one(shard_dim(mesh, shape[-1], MODEL_AXIS))
         return _spec(entries)

@@ -2,8 +2,9 @@
 
 Shapes follow (B, T, H, hd).  GQA repeats KV heads by gather-free reshape;
 sliding-window attention masks beyond the window (Mixtral).  Decode attends a
-single query token against the cache — for SWA the cache is a rolling buffer
-of ``window`` positions, which is what makes 500k-token contexts O(window).
+single query token against the cache, stored (S, KV, B, hd) — for SWA the
+cache is a rolling buffer of ``window`` positions, which is what makes
+500k-token contexts O(window).
 """
 from __future__ import annotations
 
@@ -120,23 +121,36 @@ def attention(p: dict, x: jax.Array, cfg: ModelConfig,
 # --------------------------------------------------------------------------- #
 # KV-cache serving
 # --------------------------------------------------------------------------- #
+#
+# A layer's cache holds K and V as (S, KV, B, hd): position, KV head, batch,
+# head dim, stacked (L, S, KV, B, hd) over a layer group.  This is the order
+# the compiled decode attention reads without a relayout (the batch is the
+# tiled second-minor dim), so a decode step that carries the stacked cache
+# writes one row per layer and copies nothing else.
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int, seq_len: int, dtype) -> dict:
-    """Cache for one attention layer.  SWA archs keep a rolling buffer of
-    ``sliding_window`` slots; full attention keeps all ``seq_len``."""
+    """Cache for one attention layer, (S, KV, B, hd).  SWA archs keep a
+    rolling buffer of ``sliding_window`` slots; full attention keeps all
+    ``seq_len``."""
     S = min(seq_len, cfg.sliding_window) if cfg.sliding_window else seq_len
     KV, hd = cfg.n_kv_heads, cfg.hd
     return {
-        "k": jnp.zeros((batch, S, KV, hd), dtype),
-        "v": jnp.zeros((batch, S, KV, hd), dtype),
+        "k": jnp.zeros((S, KV, batch, hd), dtype),
+        "v": jnp.zeros((S, KV, batch, hd), dtype),
     }
 
 
+def cache_order(k: jax.Array) -> jax.Array:
+    """(B, T, KV, hd) -> the cache order (T, KV, B, hd)."""
+    return jnp.transpose(k, (1, 2, 0, 3))
+
+
 def prefill_attention(p, x, cfg: ModelConfig, max_len: int = 0):
-    """Run attention AND return the layer cache, sized for subsequent decode
-    up to ``max_len`` positions (rolling buffer for SWA).  QKV is projected
-    once and shared between the attention output and the cache."""
+    """Run attention AND return the layer cache (S, KV, B, hd), sized for
+    subsequent decode up to ``max_len`` positions (rolling buffer for SWA).
+    QKV is projected once and shared between the attention output and the
+    cache."""
     B, T, D = x.shape
     H = cfg.n_heads
     q, k, v = _project_qkv(p, x, cfg)
@@ -150,52 +164,88 @@ def prefill_attention(p, x, cfg: ModelConfig, max_len: int = 0):
     out = constrain(jnp.dot(out, p["wo"]), "residual")
     max_len = max(max_len, T)
     with jax.named_scope("kv_update"):
-        if cfg.sliding_window:
-            S = min(cfg.sliding_window, max_len)
-            if T > S:
-                k, v = k[:, -S:], v[:, -S:]
-            elif S > T:
-                k = jnp.pad(k, ((0, 0), (0, S - T), (0, 0), (0, 0)))
-                v = jnp.pad(v, ((0, 0), (0, S - T), (0, 0), (0, 0)))
-            # rolling-buffer layout: position p lives at slot p % S
-            k = jnp.roll(k, T % S if T > S else 0, axis=1)
-            v = jnp.roll(v, T % S if T > S else 0, axis=1)
-        elif max_len > T:
-            k = jnp.pad(k, ((0, 0), (0, max_len - T), (0, 0), (0, 0)))
-            v = jnp.pad(v, ((0, 0), (0, max_len - T), (0, 0), (0, 0)))
+        k, v = cache_order(k), cache_order(v)
+        S = min(cfg.sliding_window, max_len) if cfg.sliding_window else max_len
+        if T > S:
+            # SWA rolling buffer of the last S positions: p lives at slot p % S
+            k, v = (jnp.roll(a[-S:], T % S, axis=0) for a in (k, v))
+        else:
+            pad = ((0, S - T), (0, 0), (0, 0), (0, 0))
+            k, v = jnp.pad(k, pad), jnp.pad(v, pad)
     return out, {"k": k, "v": v}
+
+
+def cache_slot(pos: jax.Array, S: int, cfg: ModelConfig) -> jax.Array:
+    """The cache slot of absolute position ``pos`` in an S-slot cache."""
+    return pos % S if cfg.sliding_window else pos
+
+
+def decode_qkv(p: dict, x: jax.Array, pos: jax.Array, cfg: ModelConfig):
+    """Project and rope one token x (B, 1, D) at position ``pos``: the query
+    (B, H, hd) and the cache rows of its key and value (1, KV, B, hd)."""
+    q, k, v = _project_qkv(p, x, cfg)
+    cos, sin = rotary(pos[None], cfg.hd, cfg.rope_theta)
+    q = apply_rotary(q, cos, sin)
+    k = apply_rotary(k, cos, sin)
+    return q[:, 0], cache_order(k), cache_order(v)
+
+
+def decode_attend(p: dict, q: jax.Array, k: jax.Array, v: jax.Array,
+                  valid: jax.Array | None = None) -> jax.Array:
+    """The query (B, H, hd) against one layer's keys and values
+    (S, KV, B, hd), each KV head serving its group of query heads, over
+    the slots where ``valid`` (S,) holds (all when None); projected by
+    ``wo`` to (B, 1, D)."""
+    B, H, hd = q.shape
+    KV = k.shape[1]
+    qg = q.reshape(B, KV, H // KV, hd)
+    scores = jnp.einsum("bkgd,skbd->bkgs", qg, k) / (hd ** 0.5)
+    if valid is not None:
+        scores = jnp.where(valid, scores, NEG_INF)
+    w = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
+    out = jnp.einsum("bkgs,skbd->bkgd", w, v).reshape(B, H * hd)
+    return jnp.dot(out, p["wo"])[:, None]
+
+
+def decode_valid(pos: jax.Array, S: int, cfg: ModelConfig) -> jax.Array:
+    """(S,) mask of the cache slots that a query at ``pos`` attends to."""
+    span = jnp.arange(S)
+    if cfg.sliding_window:
+        age = (pos % S - span) % S          # rolling-buffer age of each slot
+        return (age < cfg.sliding_window) & (age <= pos)
+    return span <= pos
 
 
 def decode_attention(p: dict, x: jax.Array, cache: dict, pos: jax.Array,
                      cfg: ModelConfig) -> tuple[jax.Array, dict]:
-    """One-token decode: x (B, 1, D), cache K/V (B, S, KV, hd), pos scalar
-    (current absolute position).  Returns (out (B, 1, D), new cache)."""
-    B, _, D = x.shape
-    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    S = cache["k"].shape[1]
-    q, k, v = _project_qkv(p, x, cfg)
-    cos, sin = rotary(pos[None], hd, cfg.rope_theta)
-    q = apply_rotary(q, cos, sin)
-    k = apply_rotary(k, cos, sin)
-
-    slot = pos % S if cfg.sliding_window else pos
+    """One-token decode: x (B, 1, D), one layer's cache K/V (S, KV, B, hd),
+    pos scalar (current absolute position).  Returns (out (B, 1, D), new
+    cache)."""
+    q, k, v = decode_qkv(p, x, pos, cfg)
+    S = cache["k"].shape[0]
+    slot = cache_slot(pos, S, cfg)
     with jax.named_scope("kv_update"):
-        ck = jax.lax.dynamic_update_slice(cache["k"], k, (0, slot, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cache["v"], v, (0, slot, 0, 0))
+        ck = jax.lax.dynamic_update_slice(cache["k"], k, (slot, 0, 0, 0))
+        cv = jax.lax.dynamic_update_slice(cache["v"], v, (slot, 0, 0, 0))
+    out = decode_attend(p, q, ck, cv, decode_valid(pos, S, cfg))
+    return out, {"k": ck, "v": cv}
 
-    kk = _expand_kv(ck, H)   # (B, S, H, hd)
-    vv = _expand_kv(cv, H)
-    scores = jnp.einsum("bthd,bshd->bhts", q, kk)[:, :, 0] / (hd ** 0.5)
-    span = jnp.arange(S)
-    if cfg.sliding_window:
-        age = (pos % S - span) % S          # rolling-buffer age of each slot
-        valid = (age < cfg.sliding_window) & (span < S) & (age <= pos)
-    else:
-        valid = span <= pos
-    scores = jnp.where(valid[None, None], scores, NEG_INF)
-    w = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(x.dtype)
-    out = jnp.einsum("bhs,bshd->bhd", w, vv).reshape(B, 1 * H * hd)
-    out = jnp.dot(out, p["wo"]).reshape(B, 1, D)
+
+def decode_attention_stacked(p: dict, x: jax.Array, cache: dict,
+                             layer: jax.Array, pos: jax.Array,
+                             cfg: ModelConfig) -> tuple[jax.Array, dict]:
+    """``decode_attention`` for layer ``layer`` of a stacked cache
+    (L, S, KV, B, hd): writes the token's row into the stack in place and
+    attends over that layer read from it.  Returns (out, the stack)."""
+    q, k, v = decode_qkv(p, x, pos, cfg)
+    S = cache["k"].shape[1]
+    at = (layer, cache_slot(pos, S, cfg), 0, 0, 0)
+    with jax.named_scope("kv_update"):
+        ck = jax.lax.dynamic_update_slice(cache["k"], k[None], at)
+        cv = jax.lax.dynamic_update_slice(cache["v"], v[None], at)
+    kl = jax.lax.dynamic_index_in_dim(ck, layer, 0, keepdims=False)
+    vl = jax.lax.dynamic_index_in_dim(cv, layer, 0, keepdims=False)
+    out = decode_attend(p, q, kl, vl, decode_valid(pos, S, cfg))
     return out, {"k": ck, "v": cv}
 
 

@@ -95,12 +95,12 @@ def layer_prefill(p: dict, x: jax.Array, cfg: ModelConfig, max_len: int = 0):
     return x, cache
 
 
-def layer_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array,
-                 cfg: ModelConfig):
+def layer_decode(p: dict, x: jax.Array, attend, cfg: ModelConfig):
+    """One decode layer; ``attend(attn_params, normed_x)`` is the layer's
+    cached attention and returns (out, cache)."""
     n1, n2 = _norms(p, cfg)
     with jax.named_scope("attn"):
-        a, cache = _attn(cfg).decode_attention(p["attn"], n1(x), cache, pos,
-                                                cfg)
+        a, cache = attend(p["attn"], n1(x))
         x = x + a
     with jax.named_scope("ffn"):
         x = x + ffn(p["ffn"], n2(x), cfg)
@@ -259,16 +259,33 @@ class DecoderLM:
         return caches, self._head(params, x[:, -1:])
 
     def _decode_step(self, params, cache, tokens, pos):
+        """A GQA/MHA group carries its stacked cache through the layer scan
+        and each layer writes its token's row in place; a latent (MLA)
+        group maps each layer's cache through the scan, since its two
+        contractions over the latent read it in conflicting orders."""
         x = self._embed(params, tokens[:, None])
         new_caches = {}
-        for key, ckey, cfg, _ in self.groups:
-            def body(h, xs, cfg=cfg):
-                layer_p, layer_cache = xs
-                h2, new_cache = layer_decode(self._cast(layer_p), h,
-                                             layer_cache, pos, cfg)
-                return h2, new_cache
+        for key, ckey, cfg, n in self.groups:
+            if _attn(cfg) is gqa:
+                def body(carry, xs, cfg=cfg):
+                    h, kv = carry
+                    layer_p, i = xs
+                    return layer_decode(
+                        self._cast(layer_p), h,
+                        lambda p, y: gqa.decode_attention_stacked(
+                            p, y, kv, i, pos, cfg), cfg), None
 
-            x, new_caches[ckey] = jax.lax.scan(body, x,
-                                               (params[key], cache[ckey]))
+                (x, new_caches[ckey]), _ = jax.lax.scan(
+                    body, (x, cache[ckey]), (params[key], jnp.arange(n)))
+            else:
+                def body(h, xs, cfg=cfg):
+                    layer_p, layer_cache = xs
+                    return layer_decode(
+                        self._cast(layer_p), h,
+                        lambda p, y: mla.decode_attention(
+                            p, y, layer_cache, pos, cfg), cfg)
+
+                x, new_caches[ckey] = jax.lax.scan(
+                    body, x, (params[key], cache[ckey]))
         x = self._final_norm(params, x)
         return self._head(params, x)[:, 0], new_caches

@@ -3,15 +3,17 @@
 The audio conv frontend is a STUB per the assignment: ``input_specs`` feeds
 precomputed frame embeddings (B, T_audio, d_model) directly to the encoder.
 The decoder is a causal transformer with cross-attention; decode caches both
-the self-attention KV and the per-layer cross KV projections."""
+the self-attention KV and the per-layer cross KV projections, each in the
+attention cache order (L, S, KV, B, hd)."""
 from __future__ import annotations
 
 
 import jax
 import jax.numpy as jnp
 
-from .attention import (attention, cross_attention, decode_attention,
-                        init_attn_params, init_kv_cache, prefill_attention)
+from .attention import (attention, cache_order, cross_attention,
+                        decode_attend, decode_attention, init_attn_params,
+                        init_kv_cache, prefill_attention)
 from .config import ModelConfig
 from .layers import cross_entropy_loss, init_dense, norm_fn
 from .transformer import ffn, init_ffn_params
@@ -110,17 +112,14 @@ class WhisperModel:
     # ---- serving -----------------------------------------------------------------
     def init_cache(self, batch: int, seq_len: int) -> dict:
         cfg = self.cfg
-        kv = init_kv_cache(cfg, batch, seq_len, self.dtype)
-        kv = jax.tree.map(
-            lambda a: jnp.broadcast_to(a[None], (cfg.n_layers,) + a.shape),
-            kv)
-        Ta = cfg.frontend_tokens or 1500
-        KV, hd = cfg.n_kv_heads, cfg.hd
-        cross = {
-            "k": jnp.zeros((cfg.n_layers, batch, Ta, KV, hd), self.dtype),
-            "v": jnp.zeros((cfg.n_layers, batch, Ta, KV, hd), self.dtype),
-        }
-        return {"kv": kv, "cross": cross}
+
+        def stacked(length):
+            one = init_kv_cache(cfg, batch, length, self.dtype)
+            return jax.tree.map(lambda a: jnp.broadcast_to(
+                a[None], (cfg.n_layers,) + a.shape), one)
+
+        return {"kv": stacked(seq_len),
+                "cross": stacked(cfg.frontend_tokens or 1500)}
 
     def prefill(self, params, batch, max_len: int = 0):
         """Encode audio, consume the text prompt, cache self+cross KV."""
@@ -139,6 +138,7 @@ class WhisperModel:
             h = h + a
             ck = jnp.dot(enc_out, lp["cross"]["wk"]).reshape(B, Ta, KV, hd)
             cv = jnp.dot(enc_out, lp["cross"]["wv"]).reshape(B, Ta, KV, hd)
+            ck, cv = cache_order(ck), cache_order(cv)
             h = h + cross_attention(lp["cross"], nf(h, lp["norm2"]), enc_out,
                                     cfg)
             h = h + ffn(lp["ffn"], nf(h, lp["norm3"]), cfg)
@@ -154,7 +154,7 @@ class WhisperModel:
         nf = norm_fn(cfg.norm)
         x = jnp.take(params["embed"].astype(self.dtype), tokens[:, None],
                      axis=0)
-        H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+        H, hd = cfg.n_heads, cfg.hd
 
         def body(h, xs):
             lp, kv_c, cross_c = xs
@@ -165,14 +165,8 @@ class WhisperModel:
             # cross attention against cached enc projections
             B = h.shape[0]
             q = jnp.dot(nf(h, lp["norm2"]),
-                        lp["cross"]["wq"]).reshape(B, 1, H, hd)
-            from .attention import _expand_kv
-            k = _expand_kv(cross_c["k"], H)
-            v = _expand_kv(cross_c["v"], H)
-            s = jnp.einsum("bthd,bshd->bhts", q, k) / (hd ** 0.5)
-            w = jax.nn.softmax(s.astype(jnp.float32), -1).astype(h.dtype)
-            o = jnp.einsum("bhts,bshd->bthd", w, v).reshape(B, 1, H * hd)
-            h = h + jnp.dot(o, lp["cross"]["wo"])
+                        lp["cross"]["wq"]).reshape(B, H, hd)
+            h = h + decode_attend(lp["cross"], q, cross_c["k"], cross_c["v"])
             h = h + ffn(lp["ffn"], nf(h, lp["norm3"]), cfg)
             return h, kv2
 

@@ -70,12 +70,17 @@ def test_batch_specs():
 
 
 def test_kv_cache_specs():
+    # (L, S, KV, B, hd): batch over data, KV heads over model, else head_dim
     cfg = get_config("qwen2.5-32b")   # kv=8: heads don't divide 16
-    spec = cache_spec("kv/k", (64, 128, 32768, 8, 128), MESH, cfg)
-    assert spec[3] is None and spec[4] == "model"   # head_dim sharded
+    spec = cache_spec("kv/k", (64, 32768, 8, 128, 128), MESH, cfg)
+    assert spec[3] == "data"
+    assert spec[2] is None and spec[4] == "model"   # head_dim sharded
     cfg2 = get_config("qwen1.5-32b")  # kv=40 -> not divisible either
-    spec2 = cache_spec("kv/k", (64, 128, 32768, 40, 128), MESH, cfg2)
-    assert spec2[4] == "model"
+    spec2 = cache_spec("kv/k", (64, 32768, 40, 128, 128), MESH, cfg2)
+    assert spec2[3] == "data" and spec2[4] == "model"
+    cfg3 = get_config("olmo-1b")      # kv=16 divides: heads sharded
+    spec3 = cache_spec("kv/v", (16, 544, 16, 32, 128), MESH, cfg3)
+    assert spec3 == P(None, None, "model", "data", None)
 
 
 def test_mamba_state_specs():

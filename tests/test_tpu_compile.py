@@ -5,6 +5,10 @@ do not fit VMEM, layouts Mosaic cannot tile, programs that do not fit HBM.
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
+import collections
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -120,6 +124,104 @@ def test_olmo_served_decode_step_donates_its_cache(one_chip):
     assert total < V5E_HBM_BYTES, total
 
 
+#: one instruction of an HLO module's text: name, result type, opcode, operands
+_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (.+?) ([\w\-]+)\(([^)]*)")
+#: opcodes that name or forward a buffer without writing one
+_NO_WRITE = {"parameter", "get-tuple-element", "tuple", "bitcast", "while"}
+
+
+def _first_array(hlo_type: str) -> tuple[str, int]:
+    """The first array of an HLO result type, as ``dtype[dims]`` with
+    ``{S(1)}`` appended when its layout puts it in memory space 1, and its
+    element count."""
+    m = re.search(r"(\w+\[([\d,]*)\])(\{[^}]*\})?", hlo_type)
+    n = math.prod(int(d) for d in m.group(2).split(",") if d)
+    return m.group(1) + ("{S(1)}" if "S(1)" in (m.group(3) or "") else ""), n
+
+
+def cache_results(text: str, sizes: set[int], row: int):
+    """Instructions of a compiled module (outside fused computations, which
+    write no buffer of their own) whose result has one of ``sizes``
+    elements, as (in-place row updates, the others): a row update is a
+    dynamic-update-slice, or a fusion rooted in one, that writes ``row``
+    elements into its operand."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            cur = comps.setdefault(
+                re.match(r"(?:ENTRY )?%?([\w.\-]+)", line).group(1), [])
+        elif cur is not None and (m := _INSTR.match(line)):
+            name, ty, op, args = m.groups()
+            cur.append((name, ty, op, re.findall(r"%([\w.\-]+)", args),
+                        line.lstrip().startswith("ROOT"), line))
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+
+    def writes_row(instrs, op, args, line):
+        if op == "fusion":
+            instrs = comps[re.search(r"calls=%([\w.\-]+)", line).group(1)]
+            root = next(i for i in instrs if i[4])
+            op, args = root[2], root[3]
+        types = {i[0]: i[1] for i in instrs}
+        return (op == "dynamic-update-slice"
+                and _first_array(types[args[1]])[1] == row)
+
+    rows, others = [], []
+    for cname, instrs in comps.items():
+        if cname in fused:
+            continue
+        for name, ty, op, args, _, line in instrs:
+            array, n = _first_array(ty)
+            if op in _NO_WRITE or n not in sizes:
+                continue
+            (rows if writes_row(instrs, op, args, line) else others).append(
+                (name, array))
+    return rows, others
+
+
+def _served_decode_step(one_chip, cfg, batch, seq):
+    model, params = eval_shape_params(cfg)
+    cache = eval_shape_cache(cfg, batch, seq)
+    args = shapes(one_chip, (params, cache,
+                             jax.ShapeDtypeStruct((batch,), jnp.int32),
+                             jax.ShapeDtypeStruct((), jnp.int32)))
+    return cache, model._decode_jit.lower(*args).compile()
+
+
+@pytest.mark.parametrize("arch,n_layers,batch,seq,layer_reads", [
+    ("olmo-1b", 16, 32, 544, 0),
+    # GQA, 28 heads over 4: each layer's K and V are staged once in memory
+    # space 1 (the v5e's VMEM) as the grouped dot's operand
+    ("qwen2-7b", 4, 32, 2048, 2),
+])
+def test_served_decode_step_writes_one_cache_row_per_layer(
+        one_chip, arch, n_layers, batch, seq, layer_reads):
+    """The decode step ``generate`` dispatches, at the batch-decode cell's
+    size for olmo-1b: the stacked KV cache is carried through the layer
+    scan and updated in place, one row per layer of K and of V.  No other
+    instruction writes a buffer the size of the stacked cache or of one
+    layer's cache (bar GQA's staged reads), and the temporaries stay under
+    2.5 GB (4.71 GB for olmo-1b when the scan mapped the cache)."""
+    cfg = get_config(arch).scaled(n_layers=n_layers)
+    cache, compiled = _served_decode_step(one_chip, cfg, batch, seq)
+    k = cache["kv"]["k"]
+    assert k.shape == (n_layers, seq, cfg.n_kv_heads, batch, cfg.hd)
+    rows, others = cache_results(compiled.as_text(), {k.size, k.size // n_layers},
+                                 row=cfg.n_kv_heads * batch * cfg.hd)
+    assert len(rows) == 2, rows
+    layer = f"bf16[1,{seq},{cfg.n_kv_heads},{batch},{cfg.hd}]{{S(1)}}"
+    assert [ty for _, ty in others] == [layer] * layer_reads, others
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
+#: cache-sized results of DeepSeek's served decode step (``cache_results``)
+LATENT_CACHE_WRITES = {
+    "bf16[4,32,4224,512]": 3, "bf16[32,4224,512]": 3,
+    "bf16[4,32,4224,64]": 3, "bf16[4,32,4224,64]{S(1)}": 2,
+    "bf16[32,4224,64]": 8, "bf16[32,4224,64]{S(1)}": 4,
+    "bf16[1,32,4224,64]{S(1)}": 2,
+}
+
+
 def test_deepseek_served_decode_step_fits_and_donates_its_cache(one_chip):
     """DeepSeek-V2-Lite's served decode step at the long-decode cell's
     size (the leading dense layer and 4 MoE layers holding 16 of 64
@@ -142,6 +244,11 @@ def test_deepseek_served_decode_step_fits_and_donates_its_cache(one_chip):
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert total < V5E_HBM_BYTES, total
+    # the latent cache keeps its mapped scan: the same cache-sized writes
+    sizes = {n for a in jax.tree.leaves(cache)
+             for n in (a.size, a.size // a.shape[0])}
+    _, written = cache_results(compiled.as_text(), sizes, row=-1)
+    assert collections.Counter(ty for _, ty in written) == LATENT_CACHE_WRITES
 
 
 def test_olmo_train_step_fits_four_v5e(topo):
