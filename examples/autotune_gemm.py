@@ -1,5 +1,5 @@
 """Autotuning walkthrough: search the mapping/schedule space, persist the
-winner, and watch the kernels pick it up (paper Section 4).
+winner, and hand it to the Pallas GEMM (paper Section 4).
 
     PYTHONPATH=src python examples/autotune_gemm.py
 
@@ -10,9 +10,9 @@ winner, and watch the kernels pick it up (paper Section 4).
    the result can only match or beat the paper's heuristics.
 3. Validate: the winning schedule replays bit-exact against the ISAMIR
    oracle through the executor.
-4. Persist the winner in the tuning cache and read it back the way
-   ``kernels/gemm.py`` does at run time.
-5. Run the tuned block shape through the Pallas GEMM.
+4. Persist the winner in the tuning cache and read it back.
+5. Pass the recorded tile to the Pallas GEMM as ``block=`` (the kernels
+   never read the cache themselves).
 
 The same flow over the paper's full evaluation set is the CLI:
 
@@ -27,8 +27,7 @@ from repro.core import instructions as I
 from repro.core import kernels_ir as K
 from repro.core.isel import select_instructions
 from repro.core.sysgraph import tpu_v5e
-from repro.search.cache import (TuningCache, TuningRecord, lookup_gemm,
-                                set_default_cache)
+from repro.search.cache import TuningCache, TuningRecord, lookup_gemm
 from repro.search.evaluate import (CostModelEvaluator, gemm_tile_for,
                                    validate_selection)
 from repro.search.space import ParamApproach, SearchSpace, tuning_key
@@ -68,19 +67,17 @@ cache.store(TuningRecord(
     cost=outcome.best_cost, baseline_cost=outcome.baseline_cost,
     strategy="hillclimb", trials=outcome.evaluations,
     tile=gemm_tile_for(outcome.best_config, graph, M, N, KDIM)))
-set_default_cache(cache)          # what `--tuned` launches do
-rec = lookup_gemm(M, N, KDIM)
+rec = lookup_gemm(M, N, KDIM, cache=cache)
 print(f"cache {cache_path}: tile={rec.tile} "
       f"speedup={rec.speedup:.2f}x")
 
 # 5. tuned Pallas kernel --------------------------------------------------------
 import jax.numpy as jnp
 
-from repro.kernels.gemm import gemm, tuned_block
+from repro.kernels.gemm import gemm
 from repro.kernels.ref import gemm_ref
 
-block = tuned_block(M, N, KDIM)
-assert block == rec.tile
+block = rec.tile
 rng = np.random.default_rng(0)
 a = jnp.asarray(rng.uniform(-1, 1, (M, KDIM)), jnp.float32)
 b = jnp.asarray(rng.uniform(-1, 1, (KDIM, N)), jnp.float32)

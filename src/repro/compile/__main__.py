@@ -32,8 +32,7 @@ from ..core.sysgraph import TARGET_ALIASES, TARGETS, resolve_target
 from ..runtime.spans import records, summarize
 from .artifact import CompileError
 from .cache import ArtifactCache, set_default_artifact_cache
-from .driver import (compile_conv, compile_fabric, compile_gemm, compile_gru,
-                     resolve_approach)
+from .driver import compile_conv, compile_gemm, compile_gru, resolve_approach
 
 #: Oracle proxies cap each axis (same policy as repro.search / repro.fabric).
 VALIDATE_DIM_CAP = 192
@@ -65,6 +64,7 @@ def _parse_shape(text: str, kernel: str) -> dict:
 
 def _compile_case(kernel: str, kw: dict, approach, args, graph):
     if args.chips > 1:
+        from ..fabric.simulate import compile_fabric
         from ..fabric.topology import make_topology
         topo = make_topology(args.topology, args.chips)
         if kernel == "gemm":

@@ -5,14 +5,13 @@ graph, any Approach); ``compile_gemm`` / ``compile_gru`` / ``compile_conv``
 are the workload frontends the kernels, the tuner and the benchmarks share;
 ``compile_selection`` runs the back half of the pipeline when an instruction
 selection is already in hand (the search evaluators and per-chip fabric
-compiles); ``compile_fabric`` partitions a workload across a multi-chip
-topology and returns an artifact carrying the distributed plan.
+compiles).  ``lookup_artifact`` / ``store_artifact`` are the one cache policy
+every entry shares, ``repro.fabric``'s multi-chip compile included.
 
 Every entry produces (or replays) a ``CompiledKernel``.  Fresh compiles are
 memoized in-process per artifact key; the persistent artifact cache is
 consulted when one is passed explicitly or activated process-wide
-(``repro.compile.cache.set_default_artifact_cache`` — the ``--tuned``
-launches and the CLI do this).
+(``repro.compile.cache.set_default_artifact_cache`` — the CLI does this).
 """
 from __future__ import annotations
 
@@ -109,12 +108,6 @@ _FRONTENDS = {
 # --------------------------------------------------------------------------- #
 
 
-def _resolve_cache(cache, use_cache: bool) -> ArtifactCache | None:
-    if not use_cache:
-        return None
-    return cache if cache is not None else get_default_artifact_cache()
-
-
 def _strip(art: CompiledKernel) -> CompiledKernel:
     """A detached copy holding only the serializable payload — what the memo
     keeps (and hands back) so it never pins live schedules/selections; a
@@ -127,8 +120,8 @@ def _strip(art: CompiledKernel) -> CompiledKernel:
     return s
 
 
-def _store(art: CompiledKernel, cache: ArtifactCache | None,
-           memoize: bool) -> CompiledKernel:
+def store_artifact(art: CompiledKernel, cache: ArtifactCache | None,
+                   memoize: bool) -> CompiledKernel:
     """The one store/memo policy for every compile entry."""
     if cacheable_approach(art.approach):
         if cache is not None:
@@ -142,26 +135,35 @@ def _store(art: CompiledKernel, cache: ArtifactCache | None,
 
 def _finish(ctx: CompileContext, cache: ArtifactCache | None,
             memoize: bool) -> CompiledKernel:
-    return _store(Pipeline(passes=(SchedulePass(), VerifyPass(),
-                                   LowerPass())).run(ctx),
-                  cache, memoize)
+    return store_artifact(Pipeline(passes=(SchedulePass(), VerifyPass(),
+                                           LowerPass())).run(ctx),
+                          cache, memoize)
 
 
-def _lookup(program: Program, graph: SystemGraph, approach, backend: str,
-            cache: ArtifactCache | None, memoize: bool, isa=None,
-            allow_transforms: bool = True):
-    """(key, hit) — the memo is consulted first, then the persistent cache."""
+def lookup_artifact(program: Program, graph: SystemGraph, approach,
+                    backend: str, cache: ArtifactCache | None,
+                    use_cache: bool, isa=None, allow_transforms: bool = True):
+    """(key, cache, hit): the artifact key (None for an approach that is
+    never cached), the persistent cache in effect (``cache``, else the
+    process default; None without ``use_cache``), and the memoized or
+    persisted artifact for these inputs — attached to them — or None.  The
+    memo is consulted first, then the persistent cache."""
+    if use_cache:
+        cache = cache if cache is not None else get_default_artifact_cache()
+    else:
+        cache = None
     if not cacheable_approach(approach):
-        return None, None
+        return None, cache, None
     key = artifact_key(program, graph, approach, backend, isa,
                        allow_transforms)
-    if memoize and key in _MEMO:
-        return key, _strip(_MEMO[key])
-    if cache is not None:
+    hit = None
+    if use_cache and key in _MEMO:
+        hit = _strip(_MEMO[key])
+    elif cache is not None:
         hit = cache.lookup(key)
-        if hit is not None:
-            return key, hit
-    return key, None
+    if hit is not None:
+        _attach(hit, program, graph, approach, isa, allow_transforms)
+    return key, cache, hit
 
 
 def compile_program(program: Program, graph: SystemGraph | None = None,
@@ -176,18 +178,16 @@ def compile_program(program: Program, graph: SystemGraph | None = None,
     graph = graph if graph is not None else tpu_v5e(1)
     approach = resolve_approach(approach)
     isa = list(isa) if isa else I.tpu_isa()
-    cache = _resolve_cache(cache, use_cache)
-    key, hit = _lookup(program, graph, approach, backend, cache, use_cache,
-                       isa, allow_transforms)
+    _, cache, hit = lookup_artifact(program, graph, approach, backend, cache,
+                                    use_cache, isa, allow_transforms)
     if hit is not None:
-        _attach(hit, program, graph, approach, isa, allow_transforms)
         return hit
     ctx = CompileContext(program=program, graph=graph, approach=approach,
                          isa=isa, allow_transforms=allow_transforms,
                          backend=backend, verify=verify,
                          meta=dict(meta or {}))
     ctx.meta.setdefault("allow_transforms", allow_transforms)
-    return _store(Pipeline().run(ctx), cache, memoize=use_cache)
+    return store_artifact(Pipeline().run(ctx), cache, memoize=use_cache)
 
 
 def compile_selection(selection: Selection, graph: SystemGraph,
@@ -213,15 +213,13 @@ def _compile_frontend(frontend: str, fe_args: dict, graph, approach, backend,
                       cache, use_cache, verify: bool = True) -> CompiledKernel:
     graph = graph if graph is not None else tpu_v5e(1)
     approach = resolve_approach(approach)
-    cache = _resolve_cache(cache, use_cache)
     # Frontend programs are cheap to rebuild; selections are not — key off
     # the program (+ the frontend's ISA/transform policy), select on a miss.
     program, isa, allow_transforms, _sel_builder = \
         _frontend_program(frontend, fe_args)
-    key, hit = _lookup(program, graph, approach, backend, cache, use_cache,
-                       isa, allow_transforms)
+    _, cache, hit = lookup_artifact(program, graph, approach, backend, cache,
+                                    use_cache, isa, allow_transforms)
     if hit is not None:
-        _attach(hit, program, graph, approach, isa, allow_transforms)
         hit.meta.setdefault("frontend", frontend)
         hit.meta.setdefault("frontend_args", dict(fe_args))
         return hit
@@ -287,86 +285,6 @@ def compile_conv(approach=None, graph: SystemGraph | None = None, *,
 
 
 # --------------------------------------------------------------------------- #
-# Multi-chip (fabric) compiles
-# --------------------------------------------------------------------------- #
-
-
-def compile_fabric(kernel: str, shape: tuple[int, ...], topo,
-                   axis: str | None = None, approach=None,
-                   algorithm: str = "ring", replicate_out: bool = False, *,
-                   cache: ArtifactCache | None = None,
-                   use_cache: bool = True) -> CompiledKernel:
-    """Partition ``kernel``/``shape`` across ``topo`` and compile: per-chip
-    schedules come from ``compile_selection`` and the distributed makespan
-    from the ``repro.fabric`` event simulator.  The artifact's tile plan is
-    chip 0's; ``artifact.fabric`` carries the partition + collective plan."""
-    from ..fabric.partition import partition, partition_axes
-    from ..fabric.simulate import replicate_output, simulate_partition
-    from ..fabric.topology import Topology
-
-    approach = resolve_approach(approach)
-    axis = axis or partition_axes(kernel)[0]
-    backend = (f"fabric-{topo.name}-{axis}-{algorithm}"
-               + ("-repl" if replicate_out else ""))
-    cache = _resolve_cache(cache, use_cache)
-    chip_graph = Topology.chip_graph()
-    fabric_graph = topo.build_graph()
-    pp = partition(kernel, shape, axis, topo.n_chips)
-    if replicate_out:
-        pp = replicate_output(pp)
-
-    key, hit = _lookup(pp.base, fabric_graph, approach, backend, cache,
-                       use_cache)
-    if hit is not None:
-        _attach(hit, pp.base, fabric_graph, approach, None, True)
-        return hit
-    if key is None:                        # opaque approach: key is informational
-        key = artifact_key(pp.base, fabric_graph, approach, backend)
-
-    res = simulate_partition(pp, topo, approach, algorithm, chip_graph)
-    shard0 = compile_selection(pp.shard_selection(pp.shards[0]), chip_graph,
-                               approach, program=pp.shards[0].program)
-    art = CompiledKernel(
-        key=key,
-        program_name=pp.base.name,
-        program_fp=_program_fp(pp.base),
-        graph_name=fabric_graph.name,
-        graph_fp=_graph_fp(fabric_graph),
-        approach_fp=shard0.approach_fp,
-        backend=backend,
-        cost=res.makespan,
-        instrs=shard0.instrs,
-        counts=shard0.counts,
-        bytes_moved=shard0.bytes_moved,
-        lowering=shard0.lowering,
-        fabric={"axis": pp.axis, "algorithm": res.algorithm,
-                "chips": topo.n_chips, "topology": topo.name,
-                "makespan": res.makespan, "comm_end": res.comm_end,
-                "comm_bound": res.comm_bound,
-                "collective_steps": res.n_collective_steps,
-                "chip_spans": list(res.chip_spans),
-                "out_mode": pp.out_mode,
-                "collectives": [{"kind": c.kind, "buffer": c.buffer,
-                                 "when": c.when, "axis": c.axis}
-                                for c in pp.collectives],
-                "per_chip_cost": shard0.cost},
-        meta={"kernel": kernel, "shape": list(shape)},
-        program=pp.base, graph=fabric_graph, approach=approach,
-        selection=shard0.selection, schedule=shard0.schedule)
-    return _store(art, cache, memoize=use_cache)
-
-
-def _program_fp(prog: Program) -> str:
-    from ..search.space import program_fingerprint
-    return program_fingerprint(prog)
-
-
-def _graph_fp(graph: SystemGraph) -> str:
-    from ..search.space import sysgraph_fingerprint
-    return sysgraph_fingerprint(graph)
-
-
-# --------------------------------------------------------------------------- #
 # Cache-hit replay
 # --------------------------------------------------------------------------- #
 
@@ -386,16 +304,16 @@ def recompile_schedule(art: CompiledKernel) -> None:
     graph and approach reproduce the cached decisions exactly.
 
     Fabric artifacts carry chip 0's *per-chip* schedule (what a fresh
-    ``compile_fabric`` attaches), so the rebuild re-partitions and
-    schedules shard 0 on the single-chip graph — not the unsharded program
-    on the fabric graph."""
+    ``repro.fabric.simulate.compile_fabric`` attaches), so the rebuild
+    re-partitions and schedules shard 0 on the single-chip graph — not the
+    unsharded program on the fabric graph.  That branch is the one place
+    ``repro.compile`` reaches up into ``repro.fabric`` (lazily)."""
     if art.fabric is not None:
         from ..fabric.partition import partition
-        from ..fabric.topology import Topology
         pp = partition(art.meta["kernel"], tuple(art.meta["shape"]),
                        art.fabric["axis"], art.fabric["chips"])
         shard0 = compile_selection(pp.shard_selection(pp.shards[0]),
-                                   Topology.chip_graph(), art.approach,
+                                   tpu_v5e(1), art.approach,
                                    program=pp.shards[0].program)
         art.selection = shard0.selection
         art.schedule = shard0.schedule
@@ -414,65 +332,3 @@ def recompile_schedule(art: CompiledKernel) -> None:
     ctx.selection = art.selection
     SchedulePass().run(ctx)
     art.schedule = ctx.schedule
-
-
-# --------------------------------------------------------------------------- #
-# Incremental re-scheduling across a config population
-# --------------------------------------------------------------------------- #
-
-
-class DeltaScheduler:
-    """Schedules many Approach variants of one fixed Selection, reusing the
-    unchanged per-instruction prefix of previously scheduled *anchors*.
-
-    An anchor is a fully scheduled config kept with its per-instruction
-    resume points (``core.scheduler.schedule_with_segments``).  A new key
-    whose policy triple matches an anchor and whose per-instr tiles share a
-    non-empty prefix resumes from the deepest snapshot before the first
-    changed instruction (``schedule_incremental``) — verified bit-equal to
-    the from-scratch schedule (``tests/test_search_batch.py`` and the
-    ``sch.*`` mutation classes).  Keys come from
-    ``repro.search.batch.BatchPlan.analyze``.
-    """
-
-    def __init__(self, selection: Selection, graph: SystemGraph,
-                 max_anchors: int = 8):
-        from ..core.scheduler import schedule_incremental, \
-            schedule_with_segments
-        self.sel = selection
-        self.graph = graph
-        self.max_anchors = max_anchors
-        self._full = schedule_with_segments
-        self._inc = schedule_incremental
-        #: (key, schedule, segments) of fresh runs, FIFO-trimmed
-        self.anchors: list[tuple] = []
-        self.stats = {"fresh": 0, "delta": 0}
-
-    def schedule_for(self, approach: Approach, key: tuple):
-        """The schedule for ``approach`` (whose BatchPlan key is ``key``),
-        via the deepest-prefix anchor when one applies."""
-        tiles, pol = key[0], key[1:]
-        best = None                     # (first_changed, schedule, segments)
-        for a_key, a_sched, a_segs in self.anchors:
-            if a_key[1:] != pol:
-                continue
-            n = 0
-            for ta, tb in zip(a_key[0], tiles):
-                if ta != tb:
-                    break
-                n += 1
-            # a resume needs the snapshot taken after instr n-1
-            if n >= 1 and (n - 1) in a_segs \
-                    and (best is None or n > best[0]):
-                best = (n, a_sched, a_segs)
-        if best is not None and best[0] < len(tiles):
-            sched, _ = self._inc(self.sel, self.graph, approach,
-                                 best[1], best[2], best[0])
-            self.stats["delta"] += 1
-            return sched
-        sched, segs = self._full(self.sel, self.graph, approach)
-        self.stats["fresh"] += 1
-        self.anchors.append((key, sched, segs))
-        if len(self.anchors) > self.max_anchors:
-            self.anchors.pop(0)
-        return sched

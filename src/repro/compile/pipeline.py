@@ -12,8 +12,8 @@ and assembles the result into a ``CompiledKernel`` artifact.  Each pass is a
 small object with ``run(ctx)``; custom pipelines can drop, replace or extend
 passes (the driver uses a truncated Schedule+Lower pipeline when a selection
 is already in hand; multi-chip compiles are composed in
-``driver.compile_fabric``, which runs this pipeline per chip and attaches
-the fabric partition + collective plan to the artifact).
+``repro.fabric.simulate.compile_fabric``, which runs this pipeline per chip
+and attaches the fabric partition + collective plan to the artifact).
 
 Passes reuse the existing subsystem entry points (``core.isel``,
 ``core.scheduler``) — the pipeline adds *structure*, not a parallel
@@ -35,8 +35,8 @@ from ..core.sysgraph import SystemGraph
 from ..runtime.spans import span
 from .artifact import CompiledKernel, CompileError, InstrPlan
 from .cache import (approach_fingerprint, artifact_key_from_parts,
-                    isa_fingerprint)
-from ..search import space as _space
+                    isa_fingerprint, program_fingerprint,
+                    sysgraph_fingerprint)
 
 
 @dataclass
@@ -246,8 +246,8 @@ class Pipeline:
     def assemble(ctx: CompileContext) -> CompiledKernel:
         sched = ctx.schedule
         cost = sched.makespan if sched is not None else float("inf")
-        prog_fp = _space.program_fingerprint(ctx.program)
-        graph_fp = _space.sysgraph_fingerprint(ctx.graph)
+        prog_fp = program_fingerprint(ctx.program)
+        graph_fp = sysgraph_fingerprint(ctx.graph)
         approach_fp = approach_fingerprint(ctx.approach)
         return CompiledKernel(
             key=artifact_key_from_parts(ctx.program.name, prog_fp,

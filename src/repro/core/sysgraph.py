@@ -190,13 +190,16 @@ V5E_VMEM_BYTES = 128 << 20
 V5E_VMEM_LIMIT_BYTES = 64 << 20
 V5E_ICI_BW = 50e9              # bytes/s per link
 V5E_CLOCK = 0.94e9
+#: ICI ports per chip (v5e: 4), each V5E_ICI_BW per direction.
+ICI_PORTS_PER_CHIP = 4
+#: Default per-hop ICI issue latency (sec).
+ICI_LATENCY = 1e-6
 
 
 def add_v5e_chip(g: SystemGraph, c: int, host_mem_node: str = "host") -> None:
     """Add one v5e chip (HBM + VMEM + core, PCIe-attached to the host) to
-    ``g``.  Fabric wiring between chips is layered on top by
-    ``repro.fabric.topology`` — this helper deliberately knows nothing
-    about inter-chip links."""
+    ``g``.  Links between chips are added by ``wire_ici`` — this helper
+    deliberately knows nothing about them."""
     hbm, vmem = f"hbm{c}", f"vmem{c}"
     g.add_memory(hbm, V5E_HBM_BYTES, level=1)
     g.add_memory(vmem, V5E_VMEM_BYTES, level=2)
@@ -213,20 +216,42 @@ def add_v5e_chip(g: SystemGraph, c: int, host_mem_node: str = "host") -> None:
         clock_hz=V5E_CLOCK)
 
 
-def tpu_v5e(n_cores: int = 1, host_mem: int = 512 << 30) -> SystemGraph:
-    """One v5e chip (or several connected by an ICI ring) as a system graph.
+def ici_ring(n_chips: int, ici_bw: float = V5E_ICI_BW,
+             latency: float = ICI_LATENCY
+             ) -> list[tuple[int, int, float, float]]:
+    """Directed (src chip, dst chip, bandwidth, latency) links of a 1D
+    bidirectional ICI ring.  With 2 distinct neighbours per chip the 4
+    ports bond pairwise (2x per-port bandwidth per link); the degenerate
+    2-chip ring bonds all 4 ports onto its single neighbour."""
+    links: list[tuple[int, int, float, float]] = []
+    if n_chips > 1:
+        bw = ICI_PORTS_PER_CHIP // (1 if n_chips == 2 else 2) * ici_bw
+        for i in range(n_chips):
+            j = (i + 1) % n_chips
+            links.append((i, j, bw, latency))
+            links.append((j, i, bw, latency))
+            if n_chips == 2:
+                break                      # one bonded pair, both directions
+    return links
 
-    Multi-chip wiring is delegated to ``repro.fabric.topology.ring`` — a
-    proper bidirectional ring (with the wraparound link the old ad-hoc
-    wiring was missing) whose per-direction copies are issued by the
-    receiving chip's core."""
+
+def wire_ici(g: SystemGraph, links) -> None:
+    """Add (src chip, dst chip, bandwidth, latency) links to a multi-chip
+    graph as HBM->HBM movement edges.  Each directed copy is issued by the
+    *receiving* chip's core (pull-style ICI DMA)."""
+    for a, b, bw, latency in links:
+        g.add_edge(f"hbm{a}", f"hbm{b}", bandwidth=bw, latency=latency,
+                   issuer=f"core{b}", bidirectional=False)
+
+
+def tpu_v5e(n_cores: int = 1, host_mem: int = 512 << 30) -> SystemGraph:
+    """One v5e chip (or several connected by an ICI ring, ``ici_ring``) as
+    a system graph."""
     g = SystemGraph(f"tpu_v5e_x{n_cores}", family="tpu")
     g.add_memory("host", host_mem, level=0)
     for c in range(n_cores):
         add_v5e_chip(g, c)
-    if n_cores > 1:
-        from ..fabric.topology import ring
-        ring(n_cores).wire_ici(g)
+    wire_ici(g, ici_ring(n_cores))
     return g
 
 

@@ -18,10 +18,12 @@ architectures":
                       links, with closed-form cost models;
   * ``simulate``    — an event-driven simulator replaying per-chip static
                       schedules plus collective phases on per-link/per-core
-                      timelines (``python -m repro.fabric.simulate``).
+                      timelines (``python -m repro.fabric.simulate``), and
+                      ``compile_fabric``, the multi-chip compile.
 
-``simulate`` is imported lazily (it pulls in ``repro.search``); the other
-modules are dependency-light and safe to import from ``core``.
+``simulate`` is not imported here (it pulls in ``repro.search``, and
+``python -m repro.fabric.simulate`` must not find it pre-imported).  The
+package builds on ``repro.compile`` and ``repro.core``; neither imports it.
 """
 from .collectives import (ALGORITHMS, CollectiveStep, all_gather_time,
                           all_reduce_time, reduce_scatter_time)

@@ -24,8 +24,10 @@ The reported makespan is directly comparable to the single-chip
 ``scheduler.cost_model()`` number — same compute/DMA durations, same
 semantics, one extra resource class (fabric links).
 
-``FabricEvaluator`` scores a joint (partition axis, collective algorithm,
-per-chip tiles) config for ``repro.search``.
+``compile_fabric`` wraps a simulated partition into a ``CompiledKernel``
+carrying the distributed plan; ``FabricEvaluator`` scores a joint
+(partition axis, collective algorithm, per-chip tiles) config for
+``repro.search``.
 """
 from __future__ import annotations
 
@@ -34,7 +36,11 @@ import json
 import sys
 from dataclasses import dataclass
 
-from ..compile import CompileError, compile_selection
+from ..compile import CompiledKernel, CompileError, compile_selection
+from ..compile.cache import (artifact_key, program_fingerprint,
+                             sysgraph_fingerprint)
+from ..compile.driver import (lookup_artifact, resolve_approach,
+                              store_artifact)
 from ..core.scheduler import Region, Schedule, ScheduleError, compute_time
 from ..core.sysgraph import SystemGraph
 from ..search.space import Config, ParamApproach
@@ -475,6 +481,65 @@ def replicate_output(pp: PartitionedProgram) -> PartitionedProgram:
 # --------------------------------------------------------------------------- #
 # Graph-level replay (repro.graph)
 # --------------------------------------------------------------------------- #
+
+
+def compile_fabric(kernel: str, shape: tuple[int, ...], topo: Topology,
+                   axis: str | None = None, approach=None,
+                   algorithm: str = "ring", replicate_out: bool = False, *,
+                   cache=None, use_cache: bool = True) -> CompiledKernel:
+    """Partition ``kernel``/``shape`` across ``topo`` and compile: per-chip
+    schedules come from ``compile_selection`` and the distributed makespan
+    from ``simulate_partition``.  The artifact's tile plan is chip 0's;
+    ``artifact.fabric`` carries the partition + collective plan.  Cached
+    and memoized under the same policy as every ``repro.compile`` entry."""
+    approach = resolve_approach(approach)
+    axis = axis or partition_axes(kernel)[0]
+    backend = (f"fabric-{topo.name}-{axis}-{algorithm}"
+               + ("-repl" if replicate_out else ""))
+    chip_graph = Topology.chip_graph()
+    fabric_graph = topo.build_graph()
+    pp = partition(kernel, shape, axis, topo.n_chips)
+    if replicate_out:
+        pp = replicate_output(pp)
+
+    key, cache, hit = lookup_artifact(pp.base, fabric_graph, approach,
+                                      backend, cache, use_cache)
+    if hit is not None:
+        return hit
+    if key is None:                        # opaque approach: key is informational
+        key = artifact_key(pp.base, fabric_graph, approach, backend)
+
+    res = simulate_partition(pp, topo, approach, algorithm, chip_graph)
+    shard0 = compile_selection(pp.shard_selection(pp.shards[0]), chip_graph,
+                               approach, program=pp.shards[0].program)
+    art = CompiledKernel(
+        key=key,
+        program_name=pp.base.name,
+        program_fp=program_fingerprint(pp.base),
+        graph_name=fabric_graph.name,
+        graph_fp=sysgraph_fingerprint(fabric_graph),
+        approach_fp=shard0.approach_fp,
+        backend=backend,
+        cost=res.makespan,
+        instrs=shard0.instrs,
+        counts=shard0.counts,
+        bytes_moved=shard0.bytes_moved,
+        lowering=shard0.lowering,
+        fabric={"axis": pp.axis, "algorithm": res.algorithm,
+                "chips": topo.n_chips, "topology": topo.name,
+                "makespan": res.makespan, "comm_end": res.comm_end,
+                "comm_bound": res.comm_bound,
+                "collective_steps": res.n_collective_steps,
+                "chip_spans": list(res.chip_spans),
+                "out_mode": pp.out_mode,
+                "collectives": [{"kind": c.kind, "buffer": c.buffer,
+                                 "when": c.when, "axis": c.axis}
+                                for c in pp.collectives],
+                "per_chip_cost": shard0.cost},
+        meta={"kernel": kernel, "shape": list(shape)},
+        program=pp.base, graph=fabric_graph, approach=approach,
+        selection=shard0.selection, schedule=shard0.schedule)
+    return store_artifact(art, cache, memoize=use_cache)
 
 
 def simulate_kernel_graph(kgraph, node_costs: dict, residency: dict,

@@ -1,12 +1,11 @@
 """First-class fabric descriptions (ICI ring, 2D torus, PCIe host tree).
 
 A ``Topology`` names the chips of a multi-chip system and the directed
-*fabric links* between them.  It is the single source of truth for
-inter-chip wiring: ``build_graph()`` produces the multi-chip
-``SystemGraph`` the scheduler/simulator dry-runs against (replacing the
-ad-hoc ring wiring ``sysgraph.tpu_v5e`` used to hard-code), and
-``path()``/``ring_order`` feed the collective lowering in
-``collectives.py``.
+*fabric links* between them: ``build_graph()`` produces the multi-chip
+``SystemGraph`` the scheduler/simulator dry-runs against (through
+``core.sysgraph.wire_ici``, the wiring ``tpu_v5e`` uses too; ``ring`` takes
+its links from ``core.sysgraph.ici_ring``), and ``path()``/``ring_order``
+feed the collective lowering in ``collectives.py``.
 
 Bandwidth model: a v5e chip has ``ICI_PORTS_PER_CHIP`` ICI ports of
 ``V5E_ICI_BW`` each (per direction).  A topology splits the ports evenly
@@ -20,13 +19,8 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-from ..core.sysgraph import V5E_ICI_BW, SystemGraph, add_v5e_chip
-
-#: ICI ports per chip (v5e: 4), each V5E_ICI_BW per direction.
-ICI_PORTS_PER_CHIP = 4
-
-#: Default per-hop ICI issue latency (sec).
-ICI_LATENCY = 1e-6
+from ..core.sysgraph import (ICI_LATENCY, ICI_PORTS_PER_CHIP, V5E_ICI_BW,
+                             SystemGraph, add_v5e_chip, ici_ring, wire_ici)
 
 #: PCIe bandwidth / latency for host-tree fabrics (matches sysgraph's
 #: host<->HBM edges).
@@ -109,28 +103,17 @@ class Topology:
         return min((l.bandwidth for l in chip_links), default=PCIE_BW)
 
     # -- SystemGraph construction ---------------------------------------------
-    def wire_ici(self, g: SystemGraph) -> None:
-        """Add this topology's chip-to-chip links to an existing multi-chip
-        graph as HBM<->HBM movement edges.  Each directed copy is issued by
-        the *receiving* chip's core (pull-style ICI DMA) — the per-direction
-        issuer the old ad-hoc wiring got wrong.  Host links are skipped
-        (``add_v5e_chip`` already wires PCIe)."""
-        for l in self.links:
-            if l.src == "host" or l.dst == "host":
-                continue
-            a, b = int(l.src[4:]), int(l.dst[4:])
-            g.add_edge(f"hbm{a}", f"hbm{b}", bandwidth=l.bandwidth,
-                       latency=l.latency, issuer=f"core{b}",
-                       bidirectional=False)
-
     def build_graph(self, host_mem: int = 512 << 30) -> SystemGraph:
         """The multi-chip SystemGraph: one v5e chip per fabric chip plus
-        this topology's ICI edges."""
+        this topology's chip-to-chip links as ICI edges (host links are
+        skipped: ``add_v5e_chip`` already wires PCIe)."""
         g = SystemGraph(f"tpu_v5e_{self.name}")
         g.add_memory("host", host_mem, level=0)
         for c in range(self.n_chips):
             add_v5e_chip(g, c)
-        self.wire_ici(g)
+        wire_ici(g, [(int(l.src[4:]), int(l.dst[4:]), l.bandwidth, l.latency)
+                     for l in self.links
+                     if l.src != "host" and l.dst != "host"])
         return g
 
     @staticmethod
@@ -140,28 +123,15 @@ class Topology:
         return tpu_v5e(1)
 
 
-def _bond(n_neighbors: int) -> int:
-    return max(1, ICI_PORTS_PER_CHIP // max(1, n_neighbors))
-
-
 def ring(n_chips: int, ici_bw: float = V5E_ICI_BW,
          latency: float = ICI_LATENCY) -> Topology:
-    """1D bidirectional ICI ring.  With 2 distinct neighbours per chip the
-    4 ports bond pairwise (2x per-port bandwidth per link); the degenerate
-    2-chip ring bonds all 4 ports onto its single neighbour."""
+    """1D bidirectional ICI ring over ``core.sysgraph.ici_ring``'s links
+    (the wiring of a multi-core ``tpu_v5e``)."""
     if n_chips < 1:
         raise ValueError("ring needs at least 1 chip")
-    links: list[Link] = []
-    if n_chips > 1:
-        n_nb = 1 if n_chips == 2 else 2
-        bw = _bond(n_nb) * ici_bw
-        for i in range(n_chips):
-            j = (i + 1) % n_chips
-            links.append(Link(_chip(i), _chip(j), bw, latency))
-            links.append(Link(_chip(j), _chip(i), bw, latency))
-            if n_chips == 2:
-                break                      # one bonded pair, both directions
-    return Topology(f"ring{n_chips}", n_chips, tuple(links))
+    links = tuple(Link(_chip(a), _chip(b), bw, lat)
+                  for a, b, bw, lat in ici_ring(n_chips, ici_bw, latency))
+    return Topology(f"ring{n_chips}", n_chips, links)
 
 
 def torus(rows: int, cols: int, ici_bw: float = V5E_ICI_BW,

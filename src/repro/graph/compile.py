@@ -28,10 +28,10 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ..compile.artifact import CompiledKernel
+from ..compile.cache import program_fingerprint
 from ..compile.driver import compile_program
 from ..core.instructions import tpu_isa
 from ..core.sysgraph import SystemGraph, tpu_v5e
-from ..search.space import program_fingerprint
 from .ir import GRAPH_SCHEMA, GraphError, KernelGraph, np_dtype
 
 #: fraction of VMEM the placement planner may fill with resident tensors

@@ -38,6 +38,9 @@ def _precision(dtype):
 #: what every kernel of this package hands Mosaic
 COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=V5E_VMEM_LIMIT_BYTES)
 
+#: the (bm, bn, bk) tile ``block=None`` means: one MXU pass per grid step
+DEFAULT_BLOCK = (128, 128, 128)
+
 
 def _cdiv(a: int, b: int) -> int:
     return -(-a // b)
@@ -93,52 +96,18 @@ def _matmul_kernel(a_ref, b_ref, c_ref, *, k_steps: int):
                           precision=_precision(a_ref.dtype))
 
 
-def tuned_block(m: int, n: int, k: int,
-                default: tuple[int, int, int] = (128, 128, 128)
-                ) -> tuple[int, int, int]:
-    """Block shape for an (m, n, k) GEMM from the persistent tuning cache
-    (``repro.search``), falling back to ``default`` on a cache miss.
-
-    Tune once (``python -m repro.search.tune --suite gemm``) and every later
-    process picks the winning BlockSpec up here — keyed by program
-    fingerprint, system graph, backend, and jax version.
-
-    Shapes that were *never* tuned ask the learned cost model next — when a
-    process-wide model store is active (``--tuned --tuning-model``,
-    ``repro.search.model.set_default_store``), the matmul-family ridge model
-    ranks the tile sub-space by predicted cost and its winner becomes the
-    BlockSpec.  No store / no model / any cache error keeps ``default``.
-    Whatever the source, the block is fitted to the VMEM limit.
-    """
-    from ..search.cache import CACHE_ERRORS, clamp_tile, lookup_gemm
-    try:
-        rec = lookup_gemm(m, n, k)
-    except CACHE_ERRORS:
-        rec = None
-    if rec is not None and rec.tile:
-        return fit_gemm_block(clamp_tile(rec.tile, m, n, k))
-    try:
-        from ..search.model import predict_gemm_block
-        blk = predict_gemm_block(m, n, k)
-    except CACHE_ERRORS:
-        blk = None
-    if blk is not None:
-        return fit_gemm_block(clamp_tile(blk, m, n, k))
-    return default
-
-
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def gemm(a: jax.Array, b: jax.Array,
          block: tuple[int, int, int] | None = None,
          interpret: bool = False) -> jax.Array:
     """C = A @ B with explicit VMEM tiling.
 
-    ``block=(bm, bn, bk)`` is the VMEM tile shape — normally chosen by the
-    ISAM scheduler (see ops.scheduled_gemm).  ``block=None`` consults the
-    persistent tuning cache (``tuned_block``; resolved at trace time, so a
-    cache update needs a fresh process or jit cache).  Inputs whose
-    dimensions don't divide the block are padded up and the result cropped;
-    zero padding is exact for the contraction.
+    ``block=(bm, bn, bk)`` is the VMEM tile shape — normally the compiler's
+    plan (``ops.plan_gemm``, ``ops.scheduled_gemm``) or a tile the caller
+    read from ``repro.search``.  ``block=None`` is ``DEFAULT_BLOCK``; no file
+    or environment is consulted.  Inputs whose dimensions don't divide the
+    block are padded up and the result cropped; zero padding is exact for
+    the contraction.
 
     The kernel is named ``isam_gemm``; the pad runs under the named scope
     ``isam_gemm.pad``, the crop and cast under ``isam_gemm.crop``.
@@ -147,7 +116,7 @@ def gemm(a: jax.Array, b: jax.Array,
     k2, n = b.shape
     assert k == k2, (a.shape, b.shape)
     if block is None:
-        block = tuned_block(m, n, k)
+        block = DEFAULT_BLOCK
     bm, bn, bk = (min(block[0], m), min(block[1], n), min(block[2], k))
 
     acc_dtype = jnp.float32 if a.dtype in (jnp.bfloat16, jnp.float32) else a.dtype
@@ -181,11 +150,11 @@ def gemm_bias_act(a: jax.Array, b: jax.Array, bias: jax.Array,
                   interpret: bool = False) -> jax.Array:
     """The paper's fused instruction: act(A @ B + bias) in one kernel —
     the epilogue runs on the VPU while the block is still VMEM-resident.
-    ``block=None`` consults the tuning cache, as in ``gemm``."""
+    ``block=None`` is ``DEFAULT_BLOCK``, as in ``gemm``."""
     m, k = a.shape
     _, n = b.shape
     if block is None:
-        block = tuned_block(m, n, k)
+        block = DEFAULT_BLOCK
     bm, bn, bk = (min(block[0], m), min(block[1], n), min(block[2], k))
     mp, np_, kp = _cdiv(m, bm) * bm, _cdiv(n, bn) * bn, _cdiv(k, bk) * bk
     with jax.named_scope("isam_gemm_bias_act.pad"):

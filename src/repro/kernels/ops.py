@@ -4,19 +4,22 @@
 (``repro.compile``: map -> select -> schedule -> lower against the v5e
 system graph) decides the compute-tile shape, and that decision becomes the
 Pallas BlockSpec tiling.  The compiler output *is* the kernel configuration
-— no hand-written lowering rule.  ``plan_gemm`` / ``plan_gru`` are thin
-wrappers over ``compile_gemm`` / ``compile_gru``; tiles come from the
-``CompiledKernel``'s role-keyed tile plan (derived from each mapping's
-``axis_map``), never from guessed haystack axis names.  Both planners fit
-the tile to the VMEM limit the kernels are compiled under
-(``fit_gemm_block`` / ``fit_gru_block``), so a planned tile always compiles.
+— no hand-written lowering rule, and no second tile source: the plan
+depends on the shape alone, never on a file or the environment (a tuned
+tile from ``repro.search`` is the caller's to pass as ``block=``).
+``plan_gemm`` / ``plan_gru`` are thin wrappers over ``compile_gemm`` /
+``compile_gru``; tiles come from the ``CompiledKernel``'s role-keyed tile
+plan (derived from each mapping's ``axis_map``), never from guessed
+haystack axis names.  Both planners fit the tile to the VMEM limit the
+kernels are compiled under (``fit_gemm_block`` / ``fit_gru_block``), so a
+planned tile always compiles.
 """
 from __future__ import annotations
 
 import jax
 
 from ..compile import CompileError, compile_gemm, compile_gru
-from .gemm import fit_gemm_block, gemm, gemm_bias_act, tuned_block
+from .gemm import fit_gemm_block, gemm, gemm_bias_act
 from .gru import fit_gru_block, gru_cell, gru_seq
 
 
@@ -24,20 +27,8 @@ def plan_gemm(m: int, n: int, k: int, approach: str = "greedy",
               use_cache: bool = True) -> tuple[tuple[int, int, int], float]:
     """Compile an (m, n, k) GEMM against the v5e graph through
     ``repro.compile``; return (chosen tile (bm, bn, bk), modeled seconds).
-
-    With ``use_cache`` (default), a winning config from the persistent
-    tuning cache (``repro.search``) short-circuits planning entirely — the
-    tuned tile and its modeled cost are returned as recorded.  The lookup
-    happens on every call (only the compile itself is memoized), so
-    activating a cache mid-process takes effect immediately."""
-    if use_cache:
-        from ..search.cache import (CACHE_ERRORS, clamp_tile, lookup_gemm)
-        try:
-            rec = lookup_gemm(m, n, k)
-        except CACHE_ERRORS:
-            rec = None
-        if rec is not None and rec.tile:
-            return fit_gemm_block(clamp_tile(rec.tile, m, n, k)), rec.cost
+    ``use_cache`` governs only ``repro.compile``'s artifact memo and cache,
+    as in ``compile_gemm``; the tile is the plan's either way."""
     art = compile_gemm(m, n, k, approach=approach, use_cache=use_cache)
     return fit_gemm_block(art.gemm_tile()), art.cost
 
@@ -76,5 +67,5 @@ def scheduled_gemm(a: jax.Array, b: jax.Array,
 
 __all__ = [
     "gemm", "gemm_bias_act", "gru_cell", "gru_seq",
-    "plan_gemm", "plan_gru", "scheduled_gemm", "tuned_block",
+    "plan_gemm", "plan_gru", "scheduled_gemm",
 ]

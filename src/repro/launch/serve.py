@@ -77,25 +77,8 @@ def main(argv=None):
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write the result record as JSON (mirrors "
                          "benchmarks/run.py --json)")
-    ap.add_argument("--tuned", action="store_true",
-                    help="activate the repro.search tuning cache and the "
-                         "repro.compile artifact cache for this process: "
-                         "cache-aware ISAM kernels pick up autotuned configs "
-                         "and precompiled CompiledKernel artifacts")
-    ap.add_argument("--tuning-cache", default=None, metavar="PATH",
-                    help="tuning cache path (with --tuned)")
-    ap.add_argument("--compile-cache", default=None, metavar="PATH",
-                    help="artifact cache path (with --tuned)")
-    ap.add_argument("--tuning-model", default=None, metavar="PATH",
-                    help="learned cost model store (with --tuned): untuned "
-                         "GEMM shapes get a model-predicted BlockSpec")
     args = ap.parse_args(argv)
     enable_compile_cache()
-
-    if args.tuned:
-        from .train import activate_caches
-        activate_caches(args.tuning_cache, args.compile_cache, tag="serve",
-                        model_path=args.tuning_model)
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)

@@ -59,42 +59,6 @@ def build_trainer(cfg: ModelConfig, opt_cfg: AdamWConfig, mesh,
     return model, init_state, step, (p_sh, o_sh)
 
 
-def activate_caches(tuning_path=None, compile_path=None, tag="tuned",
-                    model_path=None):
-    """--tuned: point the process at the persistent tuning cache *and* the
-    ``repro.compile`` artifact cache, so every cache-aware entry point
-    (``tuned_block``/``plan_gemm``/``compile_gemm``...) reuses recorded
-    winners and compiled artifacts.  ``model_path`` additionally activates
-    the learned-cost-model store: GEMM shapes with no cache record get a
-    model-predicted BlockSpec instead of the static default.  Shared by
-    train and serve."""
-    from ..compile.cache import ArtifactCache, set_default_artifact_cache
-    from ..search.cache import TuningCache, set_default_cache
-    cache = TuningCache(tuning_path)
-    set_default_cache(cache)
-    if model_path is not None:
-        from ..search.model import ModelStore, set_default_store
-        store = ModelStore(model_path)
-        set_default_store(store)
-        print(f"[{tag}] model store {store.path}: {len(store)} model(s)")
-    print(f"[{tag}] tuning cache {cache.path}: {len(cache)} entries")
-    for key in sorted(cache.keys()):
-        rec = cache.lookup(key)
-        print(f"[{tag}]   {rec.meta.get('case', key)}: "
-              f"{rec.speedup:.2f}x ({rec.backend}/{rec.strategy})")
-    acache = ArtifactCache(compile_path)
-    set_default_artifact_cache(acache)
-    print(f"[{tag}] compile artifact cache {acache.path}: "
-          f"{len(acache)} artifact(s)")
-    for key in sorted(acache.keys()):
-        art = acache.lookup(key)
-        if art is not None:
-            print(f"[{tag}]   {art.program_name} on {art.graph_name}: "
-                  f"cost={art.cost:.3e}s "
-                  f"lowering={art.lowering.get('kind', '-')}")
-    return cache, acache
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCHS, default="olmo-1b")
@@ -110,30 +74,8 @@ def main(argv=None):
     ap.add_argument("--model-axis", type=int, default=1)
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--inject-fault-at", type=int, default=None)
-    ap.add_argument("--tuned", action="store_true",
-                    help="activate the repro.search tuning cache for this "
-                         "process: any cache-aware ISAM kernel invoked "
-                         "(repro.kernels tuned_block/plan_gemm) picks up "
-                         "autotuned configs; the jnp model forward path is "
-                         "unaffected until Pallas kernels are wired into it "
-                         "(see ROADMAP follow-ups)")
-    ap.add_argument("--tuning-cache", default=None, metavar="PATH",
-                    help="tuning cache path (with --tuned; default: the "
-                         "repro.search default cache)")
-    ap.add_argument("--compile-cache", default=None, metavar="PATH",
-                    help="CompiledKernel artifact cache path (with --tuned; "
-                         "default: the repro.compile default cache)")
-    ap.add_argument("--tuning-model", default=None, metavar="PATH",
-                    help="learned cost model store (with --tuned): GEMM "
-                         "shapes with no tuning-cache record get a "
-                         "model-predicted BlockSpec "
-                         "(train one: python -m repro.search.model train)")
     args = ap.parse_args(argv)
     enable_compile_cache()
-
-    if args.tuned:
-        activate_caches(args.tuning_cache, args.compile_cache,
-                        model_path=args.tuning_model)
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     opt_cfg = AdamWConfig(lr=args.lr, warmup_steps=max(args.steps // 10, 1),

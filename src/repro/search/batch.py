@@ -28,10 +28,11 @@ from __future__ import annotations
 
 import numpy as np
 
-from ..core.approach import GreedyApproach
+from ..core.approach import Approach, GreedyApproach
 from ..core.instructions import is_elementwise
 from ..core.isel import Selection
-from ..core.scheduler import Scheduler
+from ..core.scheduler import (Scheduler, schedule_incremental,
+                              schedule_with_segments)
 from ..core.sysgraph import SystemGraph
 from .space import ParamApproach
 
@@ -208,3 +209,60 @@ class BatchPlan:
             if axis not in out:
                 out[axis] = np.full(len(capi), min(ext, hw_max), np.int64)
         return out
+
+
+# --------------------------------------------------------------------------- #
+# Incremental re-scheduling across a config population
+# --------------------------------------------------------------------------- #
+
+
+class DeltaScheduler:
+    """Schedules many Approach variants of one fixed Selection, reusing the
+    unchanged per-instruction prefix of previously scheduled *anchors*.
+
+    An anchor is a fully scheduled config kept with its per-instruction
+    resume points (``core.scheduler.schedule_with_segments``).  A new key
+    whose policy triple matches an anchor and whose per-instr tiles share a
+    non-empty prefix resumes from the deepest snapshot before the first
+    changed instruction (``schedule_incremental``) — verified bit-equal to
+    the from-scratch schedule (``tests/test_search_batch.py`` and the
+    ``sch.*`` mutation classes).  Keys come from ``BatchPlan.analyze``.
+    """
+
+    def __init__(self, selection: Selection, graph: SystemGraph,
+                 max_anchors: int = 8):
+        self.sel = selection
+        self.graph = graph
+        self.max_anchors = max_anchors
+        #: (key, schedule, segments) of fresh runs, FIFO-trimmed
+        self.anchors: list[tuple] = []
+        self.stats = {"fresh": 0, "delta": 0}
+
+    def schedule_for(self, approach: Approach, key: tuple):
+        """The schedule for ``approach`` (whose BatchPlan key is ``key``),
+        via the deepest-prefix anchor when one applies."""
+        tiles, pol = key[0], key[1:]
+        best = None                     # (first_changed, schedule, segments)
+        for a_key, a_sched, a_segs in self.anchors:
+            if a_key[1:] != pol:
+                continue
+            n = 0
+            for ta, tb in zip(a_key[0], tiles):
+                if ta != tb:
+                    break
+                n += 1
+            # a resume needs the snapshot taken after instr n-1
+            if n >= 1 and (n - 1) in a_segs \
+                    and (best is None or n > best[0]):
+                best = (n, a_sched, a_segs)
+        if best is not None and best[0] < len(tiles):
+            sched, _ = schedule_incremental(self.sel, self.graph, approach,
+                                 best[1], best[2], best[0])
+            self.stats["delta"] += 1
+            return sched
+        sched, segs = schedule_with_segments(self.sel, self.graph, approach)
+        self.stats["fresh"] += 1
+        self.anchors.append((key, sched, segs))
+        if len(self.anchors) > self.max_anchors:
+            self.anchors.pop(0)
+        return sched

@@ -3,9 +3,12 @@
 One JSON file maps tuning keys — ``(program fingerprint, sysgraph, backend,
 jax version)``, see ``space.tuning_key`` — to the winning config vector plus
 provenance (strategy, trials, modeled costs, resolved GEMM tile).  The cache
-is what makes search pay off across runs: ``kernels/gemm.py`` and the
-benchmarks consult it at run time, so a shape tuned once keeps its schedule
-until the toolchain (jax version) or machine description changes.
+is what makes search pay off across runs: the tuner, ``repro verify
+--tuned`` and the modeled benchmarks read it back, so a shape tuned once
+keeps its schedule until the toolchain (jax version) or machine description
+changes.  The kernels never read it: a caller that wants a tuned tile looks
+it up (``lookup_gemm``) and passes it to ``repro.kernels.gemm`` as
+``block=``.
 
 Writes are atomic (tmp + rename) and reads are tolerant: a missing file is
 an empty cache; a *corrupt* file is an empty cache too, but warns once per
@@ -13,65 +16,18 @@ path so a damaged cache never degrades performance silently.
 """
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import tempfile
-import warnings
 from dataclasses import dataclass, field
 from functools import lru_cache
 
-try:                                    # POSIX advisory locks
-    import fcntl
-except ImportError:                     # pragma: no cover - non-POSIX
-    fcntl = None
+from ..compile.cache import file_lock, warn_corrupt_cache
 
 SCHEMA_VERSION = 1
 
 #: Override the default cache location (e.g. in CI).
 CACHE_ENV_VAR = "REPRO_TUNING_CACHE"
-
-#: The error types a persistent-cache lookup can legitimately raise — what
-#: cache-consulting call sites (``kernels.ops.plan_gemm``,
-#: ``kernels.gemm.tuned_block``) catch instead of a bare ``Exception``.
-#: Shared with the ``repro.compile`` artifact cache.
-CACHE_ERRORS = (OSError, ValueError, KeyError, TypeError)
-
-_warned_corrupt: set[str] = set()
-
-
-def warn_corrupt_cache(path: str, err: Exception) -> None:
-    """Warn exactly once per path about an unreadable cache file (the
-    tuning cache and the ``repro.compile`` artifact cache both degrade a
-    corrupt file to an empty cache, but never silently)."""
-    if path in _warned_corrupt:
-        return
-    _warned_corrupt.add(path)
-    warnings.warn(f"ignoring corrupt cache file {path}: {err}", stacklevel=3)
-
-
-@contextlib.contextmanager
-def file_lock(path: str):
-    """Advisory inter-process lock on ``path + '.lock'``.
-
-    Serializes the merge-on-save read-modify-write of the persistent caches
-    so parallel tuner workers (``tune --workers N``) cannot interleave
-    between a save's re-read and its atomic replace — without the lock a
-    racing pair can each merge against the *pre*-race file and the second
-    ``os.replace`` silently drops the first writer's keys.  Locking is
-    best-effort: on platforms without ``fcntl`` the context is a no-op and
-    saves fall back to the documented last-writer-wins-per-key race."""
-    if fcntl is None:                   # pragma: no cover - non-POSIX
-        yield
-        return
-    lock_path = os.path.abspath(path) + ".lock"
-    os.makedirs(os.path.dirname(lock_path), exist_ok=True)
-    with open(lock_path, "w") as f:
-        fcntl.flock(f, fcntl.LOCK_EX)
-        try:
-            yield
-        finally:
-            fcntl.flock(f, fcntl.LOCK_UN)
 
 
 def default_cache_path() -> str:
@@ -234,7 +190,7 @@ class TuningCache(JsonStore):
 
 
 # --------------------------------------------------------------------------- #
-# Process-wide default cache (what the kernels consult at run time)
+# Process-wide default cache (``REPRO_TUNING_CACHE`` or the home cache)
 # --------------------------------------------------------------------------- #
 
 _default_cache: TuningCache | None = None
@@ -248,20 +204,19 @@ def get_default_cache() -> TuningCache:
 
 
 def set_default_cache(cache: TuningCache | None) -> None:
-    """Point the process at a specific cache (tests, --tuned launches)."""
+    """Point the process at a specific cache (the search CLI, tests)."""
     global _default_cache
     _default_cache = cache
 
 
 # --------------------------------------------------------------------------- #
-# GEMM convenience lookups (the kernels' entry point)
+# GEMM convenience lookups
 # --------------------------------------------------------------------------- #
 
 
 def clamp_tile(tile, m: int, n: int, k: int) -> tuple[int, int, int]:
     """Clamp a recorded/requested (bm, bn, bk) tile to an (m, n, k) problem
-    — the one definition shared by ``kernels.gemm.tuned_block``,
-    ``kernels.ops.plan_gemm`` and ``search.evaluate.gemm_tile_for``."""
+    (``search.evaluate.gemm_tile_for``)."""
     bm, bn, bk = (int(x) for x in tile)
     return (max(1, min(bm, m)), max(1, min(bn, n)), max(1, min(bk, k)))
 

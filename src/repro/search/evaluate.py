@@ -137,7 +137,7 @@ class CostModelEvaluator:
         try:
             if self.incremental:
                 if self._delta is None:
-                    from ..compile.driver import DeltaScheduler
+                    from .batch import DeltaScheduler
                     self._delta = DeltaScheduler(self.sel, self.graph)
                 sched = self._delta.schedule_for(approach, key)
                 self.stats.fresh = self._delta.stats["fresh"]
@@ -220,7 +220,7 @@ class LearnedEvaluator:
         self.model = model
         self.sel = selection
         self.graph = graph
-        from ..compile.features import role_extents
+        from .features import role_extents
         self._guard = CostModelEvaluator(selection, graph,
                                          max_tiles=max_tiles)
         self._predict = model.predictor(selection.program, graph,
@@ -236,8 +236,6 @@ class LearnedEvaluator:
     def for_selection(cls, selection: Selection, graph: SystemGraph,
                       store=None, backend: str = "cost"
                       ) -> "LearnedEvaluator | None":
-        from .model import get_default_store
-        store = store if store is not None else get_default_store()
         if store is None:
             return None
         model = store.model_for(selection.program, graph, backend)

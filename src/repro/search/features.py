@@ -31,6 +31,7 @@ from ..core.approach import (DEVICE_POLICIES, SOURCE_POLICIES,
 from ..core.dtypes import dtype_bytes
 from ..core.ir import Program
 from ..core.sysgraph import SystemGraph
+from .space import ParamApproach
 
 #: Bumped whenever the feature definition changes — stored models carry it
 #: and are ignored (graceful fallback) on mismatch.
@@ -166,7 +167,6 @@ def config_features(config: dict,
     let one linear model learn "capping j on a 64-wide GEMM is free, capping
     i on a 5124-row one costs passes", which raw cap values cannot express.
     """
-    from ..search.space import ParamApproach
     pa = ParamApproach(config)
     roles = roles or {}
     feats: dict[str, float] = {}

@@ -3,7 +3,7 @@
 The paper's search framework explicitly reserves a slot for "machine
 learning to facilitate this search problem".  This module is that leg: a
 deterministic, numpy-only **ridge regression** over the engineered feature
-vectors of ``repro.compile.features``, trained on records harvested from the
+vectors of ``repro.search.features``, trained on records harvested from the
 persistent tuning cache plus fresh ``CostModelEvaluator`` labels, predicting
 ``log(modeled cost)``.
 
@@ -22,8 +22,8 @@ file.  Consumers:
   * ``search.strategies.surrogate_search`` — ranks a large candidate pool by
     predicted cost and spends the real trial budget on the top of the
     ranking;
-  * ``kernels.gemm.tuned_block`` — on a tuning-cache miss, a process-default
-    model picks the BlockSpec tile for never-tuned shapes.
+  * ``predict_gemm_block`` — for a never-tuned GEMM shape, the model picks a
+    BlockSpec tile a caller can pass to ``repro.kernels.gemm``.
 
 CLI::
 
@@ -45,13 +45,14 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from ..compile.features import (FEATURE_SCHEMA, feature_dict, feature_names,
-                                program_family, role_extents)
+from ..compile.cache import CACHE_ERRORS, jax_version, sysgraph_fingerprint
 from ..core.ir import Program
 from ..core.sysgraph import SystemGraph
-from .cache import CACHE_ERRORS, JsonStore, TuningCache
-from .space import (Config, SearchSpace, config_key, jax_version,
-                    sysgraph_fingerprint)
+from .cache import JsonStore, TuningCache
+from .features import (FEATURE_SCHEMA, _default_roles, _interactions,
+                       config_features, feature_dict, feature_names,
+                       program_family, role_extents)
+from .space import Config, SearchSpace, config_key
 
 MODEL_SCHEMA = 1
 
@@ -242,8 +243,6 @@ class CostModel:
         """A fast ``config -> predicted cost`` closure with the static
         (program/graph/role) features precomputed once.  Also exposes
         ``.predict_many(configs) -> np.ndarray`` for pool ranking."""
-        from ..compile.features import (_default_roles, _interactions,
-                                        config_features)
         roles = roles or _default_roles(prog)
         static = feature_dict({}, prog, graph, roles)
         rf = {k: static[k] for k in static if k.startswith("log_role_")}
@@ -410,36 +409,20 @@ class ModelStore(JsonStore):
         return self.lookup(model_key(program_family(prog), graph, backend))
 
 
-_default_store: ModelStore | None = None
-
-
-def get_default_store() -> ModelStore | None:
-    """The process-wide model store, if one was activated (``--tuned``
-    launches / tests).  Unlike the tuning cache this defaults to **None**:
-    learned predictions only happen when explicitly opted in."""
-    return _default_store
-
-
-def set_default_store(store: ModelStore | None) -> None:
-    global _default_store
-    _default_store = store
-
-
 def predict_gemm_block(m: int, n: int, k: int, store: ModelStore | None = None,
                        graph: SystemGraph | None = None
                        ) -> tuple[int, int, int] | None:
     """Model-picked (bm, bn, bk) BlockSpec for a *never-tuned* GEMM shape:
     rank the tile sub-space (policies at baseline) plus the model's anchors
-    by predicted cost and return the winner's resolved tile.  ``None`` when
-    no model store is active or no matmul-family model exists — the caller
-    (``kernels.gemm.tuned_block``) keeps its static default.  Pure numpy
-    prediction: safe to call at jit trace time.
+    by predicted cost and return the winner's resolved tile.  ``None``
+    without a ``store`` or when it holds no matmul-family model — the caller
+    keeps its own tile.  Pure numpy prediction: safe to call at jit trace
+    time.
 
     Candidates go through the same tile-count guard the search evaluators
     use — the model never trains on infeasible points, so an extrapolating
     prediction must not be able to hand a degenerate BlockSpec to a real
     Pallas kernel."""
-    store = store if store is not None else get_default_store()
     if store is None:
         return None
     from ..compile import gemm_selection

@@ -12,18 +12,18 @@ The distinguished ``baseline()`` point reproduces ``GreedyApproach``
 the baseline first can never report a config worse than the paper's
 heuristics.
 
-Fingerprinting: cache keys must survive process restarts and distinguish
-programs/machines structurally, so they hash ``Program.signature()`` and the
-system graph's node/edge structure rather than relying on names alone.
+``tuning_key`` keys the tuning cache on the structural fingerprints of
+``repro.compile.cache``, so records survive process restarts and tell
+programs/machines apart by structure rather than by name.
 """
 from __future__ import annotations
 
-import functools
-import hashlib
 import random
 from dataclasses import dataclass
 from typing import Iterator, Mapping
 
+from ..compile.cache import (jax_version, program_fingerprint,
+                             sysgraph_fingerprint)
 from ..core.approach import (Approach, DEVICE_POLICIES, SOURCE_POLICIES,
                              UNROLL_POLICIES)
 from ..core.ir import Program
@@ -200,55 +200,6 @@ class SearchSpace:
 def config_key(config: Config) -> tuple:
     """Hashable canonical form of a config vector (for dedup / storage)."""
     return tuple(sorted(config.items()))
-
-
-# --------------------------------------------------------------------------- #
-# Fingerprinting
-# --------------------------------------------------------------------------- #
-
-
-def _short_hash(text: str) -> str:
-    return hashlib.sha256(text.encode()).hexdigest()[:16]
-
-
-@functools.lru_cache(maxsize=512)
-def program_fingerprint(prog: Program) -> str:
-    """Stable structural hash of a haystack program (axes, buffers, access
-    matrices) — survives renaming-free rebuilds across processes.  Cached
-    (Program is frozen/hashable): artifact keying sits on the evaluator hot
-    path and re-fingerprints the same program every trial."""
-    return _short_hash(prog.signature())
-
-
-def sysgraph_fingerprint(graph: SystemGraph) -> str:
-    """Structural hash of a system graph: target family, memory
-    capacities/levels/roles, compute capabilities, and movement edges.
-    Two targets that differ in any of these can never share an artifact,
-    tuning record or learned model (the cross-backend isolation the
-    portability tests pin down)."""
-    parts = [graph.name, f"F{getattr(graph, 'family', 'generic')}"]
-    for m in sorted(graph.memories.values(), key=lambda m: m.name):
-        parts.append(f"M{m.name}:{m.capacity}:{m.level}:{m.role}")
-    for c in sorted(graph.computes.values(), key=lambda c: c.name):
-        parts.append(f"C{c.name}:{c.memory}:{sorted(c.instructions)}:"
-                     f"{c.flops_per_sec}:{c.matmul_tile}:{c.vector_lanes}:"
-                     f"{c.clock_hz}")
-    for e in sorted(graph.edges, key=lambda e: (e.src, e.dst)):
-        parts.append(f"E{e.src}>{e.dst}:{e.bandwidth}:{e.latency}")
-    return _short_hash(";".join(parts))
-
-
-@functools.lru_cache(maxsize=1)
-def jax_version() -> str:
-    """jax version without importing jax (keeps core/search numpy-only).
-    Cached: the metadata scan costs milliseconds and the result is a
-    process-constant, while ``tuning_key``/``artifact_key`` sit on the
-    evaluator hot path."""
-    try:
-        from importlib.metadata import version
-        return version("jax")
-    except Exception:  # pragma: no cover - metadata unavailable
-        return "unknown"
 
 
 def tuning_key(prog: Program, graph: SystemGraph | str,

@@ -11,7 +11,6 @@
                                                       #   goodput both ways
     repro servesim --cache arts.json                  # warm through a cache
     repro servesim --cache arts.json --expect-cached  # 2nd run: 0 fresh
-    repro servesim --tuning-model models.json         # PR 5 learned blocks
     repro servesim --verify --json report.json
 
 Exit status: 0 iff the run completes every request, ``--verify`` finds no
@@ -73,9 +72,6 @@ def main(argv=None) -> int:
                     help="artifact cache for the bucket-lattice warmup")
     ap.add_argument("--expect-cached", action="store_true",
                     help="fail unless warmup performs zero fresh compiles")
-    ap.add_argument("--tuning-model", default=None, metavar="PATH",
-                    help="learned cost-model store: predict blocks for "
-                         "never-tuned shapes (the PR 5 path)")
     ap.add_argument("--verify", action="store_true",
                     help="run the srv.* trace verifier on the result")
     ap.add_argument("--json", default=None, help="write the report here")
@@ -85,10 +81,6 @@ def main(argv=None) -> int:
     from .bucket import DEFAULT_BUCKETS, ServingPool
     from .simulate import ServeParams, simulate_serving
     from .workload import generate_requests
-
-    if args.tuning_model:
-        from ..search.model import ModelStore, set_default_store
-        set_default_store(ModelStore(args.tuning_model))
 
     archs = tuple(a.strip() for a in args.archs.split(",") if a.strip())
     buckets = DEFAULT_BUCKETS if args.buckets is None else \

@@ -15,9 +15,7 @@ existing ``ArtifactCache``, so
 Every artifact is re-verified at admission time — ``verify_graph`` +
 ``verify_placement`` on the compiled graph — before it may serve traffic;
 a corrupt artifact is evicted and recompiled fresh (warn-once, never a
-crash).  When a learned-model store is active (``repro.search.model``,
-the PR 5 path), the tuned kernels inside ``compile_program`` consult it
-for never-tuned shapes; the pool itself stays policy-free.
+crash).  The pool itself stays policy-free.
 """
 from __future__ import annotations
 

@@ -13,16 +13,16 @@ import sys
 import numpy as np
 import pytest
 
-from repro.compile.features import (feature_dict, feature_names,
-                                    feature_vector, program_family,
-                                    role_extents)
 from repro.core import kernels_ir as K
 from repro.core.approach import GreedyApproach
 from repro.core.scheduler import schedule
 from repro.core.sysgraph import paper_accelerator, tpu_v5e
-from repro.search.cache import TuningCache, TuningRecord, set_default_cache
+from repro.search.cache import TuningCache, TuningRecord
 from repro.search.evaluate import CostModelEvaluator, LearnedEvaluator
-from repro.search.model import MIN_TRAIN_SAMPLES, ModelStore, fresh_labels, harvest_cache, model_key, predict_gemm_block, set_default_store, train_family, train_suites
+from repro.search.features import (feature_dict, feature_names,
+                                   feature_vector, program_family,
+                                   role_extents)
+from repro.search.model import MIN_TRAIN_SAMPLES, ModelStore, fresh_labels, harvest_cache, model_key, predict_gemm_block, train_family, train_suites
 from repro.search.space import SearchSpace, tuning_key
 from repro.search.strategies import hill_climb, surrogate_search
 from repro.search.tune import build_cases, tune_case
@@ -314,38 +314,12 @@ def test_train_suites_trains_and_stores(tmp_path):
 
 
 # --------------------------------------------------------------------------- #
-# learned tuned_block path
+# learned GEMM block prediction
 # --------------------------------------------------------------------------- #
 
 
 def test_predict_gemm_block_requires_store(tmp_path):
     assert predict_gemm_block(64, 64, 64, store=None) is None
-
-
-def test_tuned_block_uses_model_on_cache_miss(tmp_path):
-    case = _small_gemm_case()
-    graph = tpu_v5e(1)
-    samples = fresh_labels(case, graph, n=40, seed=0)
-    model, _ = train_family(model_key("matmul", graph), "matmul", samples,
-                            graph)
-    store = ModelStore(str(tmp_path / "m.json"))
-    store.store(model)
-
-    from repro.kernels.gemm import tuned_block
-    set_default_cache(TuningCache(str(tmp_path / "empty_cache.json")))
-    try:
-        without = tuned_block(512, 384, 640)
-        set_default_store(store)
-        with_model = tuned_block(512, 384, 640)
-    finally:
-        set_default_store(None)
-        set_default_cache(None)
-    assert without == (128, 128, 128)           # static default
-    m, n, k = 512, 384, 640
-    assert all(1 <= t for t in with_model)
-    assert with_model[0] <= m and with_model[1] <= n and with_model[2] <= k
-    blk = predict_gemm_block(m, n, k, store=store)
-    assert with_model == blk                     # same decision path
 
 
 # --------------------------------------------------------------------------- #

@@ -13,14 +13,15 @@ import os
 import pytest
 
 from repro.compile import compile_conv, compile_gemm, compile_gru
-from repro.compile.cache import ArtifactCache, artifact_key
+from repro.compile.cache import (ArtifactCache, artifact_key,
+                                 sysgraph_fingerprint)
 from repro.core.approach import GreedyApproach
 from repro.core import kernels_ir as K
 from repro.core.sysgraph import (GPU_SMEM_BYTES, GPU_SMS_PER_CLUSTER,
                                  TARGET_ALIASES, TARGETS, gpu_sm,
                                  paper_accelerator, resolve_target, tpu_v5e)
 from repro.search.model import model_key
-from repro.search.space import sysgraph_fingerprint, tuning_key
+from repro.search.space import tuning_key
 from repro.search.tune import build_cases, tune_case
 from repro.verify import verify_artifact_dict
 from repro.verify.mutate import MUTATIONS

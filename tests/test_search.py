@@ -10,6 +10,7 @@ import sys
 import numpy as np
 import pytest
 
+from repro.compile.cache import program_fingerprint, sysgraph_fingerprint
 from repro.core import instructions as I
 from repro.core import kernels_ir as K
 from repro.core.approach import GreedyApproach
@@ -19,9 +20,7 @@ from repro.core.sysgraph import paper_accelerator, tpu_v5e
 from repro.search.cache import (TuningCache, TuningRecord, lookup_gemm,
                                 set_default_cache)
 from repro.search.evaluate import CostModelEvaluator, validate_selection
-from repro.search.space import (ParamApproach, SearchSpace, config_key,
-                                program_fingerprint, sysgraph_fingerprint,
-                                tuning_key)
+from repro.search.space import ParamApproach, SearchSpace, config_key, tuning_key
 from repro.search.strategies import STRATEGIES, hill_climb
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

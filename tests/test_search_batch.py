@@ -9,12 +9,12 @@ import random
 
 import pytest
 
-from repro.compile.driver import (DeltaScheduler, conv_selection,
-                                  gemm_selection, gru_selection)
+from repro.compile.driver import (conv_selection, gemm_selection,
+                                  gru_selection)
 from repro.core.scheduler import (schedule, schedule_incremental,
                                   schedule_with_segments)
 from repro.core.sysgraph import paper_accelerator, tpu_v5e
-from repro.search.batch import BatchPlan
+from repro.search.batch import BatchPlan, DeltaScheduler
 from repro.search.evaluate import CostModelEvaluator
 from repro.search.space import ParamApproach, SearchSpace, config_key
 from repro.search.strategies import STRATEGIES
