@@ -24,6 +24,10 @@ from ..runtime.spans import records, span, summarize
 from .compile_cache import enable_compile_cache
 
 
+def _nbytes(tree) -> int:
+    return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
+
+
 def generate(model, params, batch, max_new: int, greedy: bool = True,
              rng=None):
     """Prefill the prompt, then decode until ``max_new`` tokens exist.
@@ -32,10 +36,20 @@ def generate(model, params, batch, max_new: int, greedy: bool = True,
     token the (B, V) logits it was chosen from — ``logits[:, 0]`` from the
     prefill, ``logits[:, i]`` from the i-th decode step.
 
-    Records the spans ``generate`` (the root), ``generate.prefill``,
-    ``generate.decode`` and one ``generate.decode_step`` per step
-    (``repro.runtime.spans``).  Nothing here waits for the device, so
-    once the model's steps are compiled the spans time their dispatch."""
+    A call that decodes (``max_new > 1``) casts the stored weights to the
+    compute dtype once, before the prefill, with the model's
+    ``serving_weights`` (``DecoderLM``), and passes the cast weights to
+    the prefill and to every decode step, which then cast nothing.  A call
+    of one token, and a model without ``serving_weights`` (``HybridLM``,
+    ``WhisperModel``, ``XLSTMLM``), leave the casts to the programs.
+
+    Records the spans ``generate`` (the root), ``generate.cast_weights``
+    (attrs ``bytes_in``, the weights passed in, and ``bytes_out``, the
+    weights the steps get; a decoding call of a model with
+    ``serving_weights``), ``generate.prefill``, ``generate.decode`` and one
+    ``generate.decode_step`` per step (``repro.runtime.spans``).  Nothing
+    here waits for the device, so once the model's programs are compiled
+    the spans time their dispatch."""
     cfg = model.cfg
     tokens = batch["tokens"]
     B, T = tokens.shape
@@ -50,6 +64,10 @@ def generate(model, params, batch, max_new: int, greedy: bool = True,
         return jax.random.categorical(k, logits).astype(jnp.int32)
 
     with span("generate", batch=B, prompt_len=T, max_new=max_new):
+        if max_new > 1 and hasattr(model, "serving_weights"):
+            with span("generate.cast_weights", bytes_in=_nbytes(params)) as rec:
+                params = model.serving_weights(params)
+                rec.attrs["bytes_out"] = _nbytes(params)
         with span("generate.prefill"):
             cache, logits = model.prefill(params, batch, max_len=max_len)
         seen = [logits[:, -1]]

@@ -12,6 +12,9 @@ multi-head latent attention (``mla.py``).
 activation sharding context, run a ``jax.jit`` of their implementation that
 each model instance compiles once per input shape; under an outer trace or
 inside a sharding context they trace their implementation inline.
+``DecoderLM.serving_weights`` casts the stored weights to the compute dtype
+ahead of those programs, which then cast nothing and compute the same
+numbers: a caller that runs many of them casts once.
 """
 from __future__ import annotations
 
@@ -132,6 +135,7 @@ class DecoderLM:
         # serving entry points, compiled once per instance and input shape
         self._prefill_jit = jax.jit(self._prefill, static_argnames="max_len")
         self._decode_jit = jax.jit(self._decode_step, donate_argnames="cache")
+        self._cast_jit = jax.jit(self._cast)
 
     # ---- parameters -------------------------------------------------------
     def init(self, rng) -> dict:
@@ -150,6 +154,22 @@ class DecoderLM:
             p["lm_head"] = init_dense(ks[2], cfg.d_model, cfg.vocab_size,
                                       self.pdtype)
         return p
+
+    def serving_weights(self, params) -> dict:
+        """``params`` with the weights the programs cast to the compute dtype
+        already cast: each layer group's stored-dtype leaves (``_cast``'s
+        rule), ``embed`` and ``lm_head``.  Every other leaf (``norm_f``)
+        is passed through.  The programs' own casts then compile to nothing
+        and their arithmetic is unchanged.  The identity when the stored
+        dtype is the compute dtype; one compiled program when
+        ``_dispatch_now``, else traced inline (``params`` may then be
+        ``jax.ShapeDtypeStruct``s, under ``jax.eval_shape``)."""
+        if self.pdtype == self.dtype:
+            return params
+        keys = [key for key, *_ in self.groups] + ["embed", "lm_head"]
+        stored = {k: params[k] for k in keys if k in params}
+        cast = self._cast_jit if _dispatch_now() else self._cast
+        return {**params, **cast(stored)}
 
     # ---- embedding / head ----------------------------------------------------
     def _cast(self, tree):
@@ -230,10 +250,13 @@ class DecoderLM:
         return self._decode_step(params, cache, tokens, pos)
 
     def lower_serving(self, params, batch, max_len: int):
-        """The programs that ``prefill`` and ``decode_step`` dispatch to for
-        ``batch``'s shape and a cache of ``max_len`` positions, lowered
-        (``jax.stages.Lowered``: compile one to read its HLO or its
-        memory); arguments may be ``jax.ShapeDtypeStruct``s."""
+        """The programs that ``prefill`` and ``decode_step`` dispatch to in a
+        decoding call (``launch.serve.generate``, which passes them
+        ``serving_weights(params)``) for ``batch``'s shape and a cache of
+        ``max_len`` positions, lowered (``jax.stages.Lowered``: compile one
+        to read its HLO or its memory); arguments may be
+        ``jax.ShapeDtypeStruct``s."""
+        params = jax.eval_shape(self.serving_weights, params)
         prefill = self._prefill_jit.lower(params, batch, max_len=max_len)
         cache = jax.eval_shape(
             lambda p, b: self._prefill(p, b, max_len=max_len)[0], params, batch)

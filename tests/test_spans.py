@@ -222,6 +222,7 @@ def test_serve_main_prints_span_summary(monkeypatch, capsys):
     assert s["generate"]["count"] == 1
     assert s["generate.decode"]["count"] == 1
     assert s["generate.decode_step"]["count"] == 2
+    assert s["generate.cast_weights"]["count"] == 1
     # cold: each compiled entry point lowers once, at its first call
     assert s["generate.prefill"]["counters"].get("lowerings", 0) <= 1
     assert s["generate.decode"]["counters"].get("lowerings", 0) <= 1

@@ -251,6 +251,61 @@ def test_deepseek_served_decode_step_fits_and_donates_its_cache(one_chip):
     assert collections.Counter(ty for _, ty in written) == LATENT_CACHE_WRITES
 
 
+def _serving_weights(one_chip, model, params):
+    """The shapes of the weights a decoding ``generate`` call passes to the
+    programs (``DecoderLM.serving_weights``), placed on ``one_chip``."""
+    return shapes(one_chip, jax.eval_shape(model.serving_weights, params))
+
+
+def test_olmo_served_decode_step_on_cast_weights_casts_nothing(one_chip):
+    """olmo-1b's decode step at the batch-decode cell's size, 32 x 544,
+    given the weights ``generate`` casts once per call: no instruction
+    converts an argument, and the temporaries, which were the f32 weight
+    stacks cast whole (2.148 GB), all but vanish."""
+    cfg = get_config("olmo-1b")
+    model, params = eval_shape_params(cfg)
+    cache = eval_shape_cache(cfg, 32, 544)
+    args = shapes(one_chip, (cache, jax.ShapeDtypeStruct((32,), jnp.int32),
+                             jax.ShapeDtypeStruct((), jnp.int32)))
+    compiled = model._decode_jit.lower(
+        _serving_weights(one_chip, model, params), *args).compile()
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    arguments = {m.group(1) for line in entry.splitlines()
+                 if (m := _INSTR.match(line)) and m.group(3) == "parameter"}
+    assert len(arguments) == len(jax.tree.leaves((params, cache))) + 2
+    converted = [m.group(1) for line in text.splitlines()
+                 if (m := _INSTR.match(line)) and m.group(3) == "convert"
+                 and arguments & set(re.findall(r"%([\w.\-]+)", m.group(4)))]
+    assert converted == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_deepseek_served_programs_on_cast_weights_fit_one_v5e(one_chip, program):
+    """The DeepSeek cell's prefill (32 x 4096 into 4224 positions) and
+    decode step given the weights ``generate`` casts once per call fit one
+    chip's HBM beside the f32 weights the caller keeps (4.72 GB)."""
+    cfg = get_config("deepseek-v2-lite").scaled(n_layers=5, held_experts=16)
+    model, params = eval_shape_params(cfg)
+    weights = _serving_weights(one_chip, model, params)
+    if program == "prefill":
+        batch = shapes(one_chip, {"tokens": jax.ShapeDtypeStruct((32, 4096), jnp.int32)})
+        compiled = model._prefill_jit.lower(weights, batch, max_len=4224).compile()
+    else:
+        args = shapes(one_chip, (eval_shape_cache(cfg, 32, 4224),
+                                 jax.ShapeDtypeStruct((32,), jnp.int32),
+                                 jax.ShapeDtypeStruct((), jnp.int32)))
+        compiled = model._decode_jit.lower(weights, *args).compile()
+    mem = compiled.memory_analysis()
+    stored = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    assert mem.argument_size_in_bytes < stored      # the bf16 copy, not the f32
+    total = (stored + mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < V5E_HBM_BYTES, total
+
+
 def test_olmo_train_step_fits_four_v5e(topo):
     """olmo-1b training at full width, tensor-parallel over a (data=1,
     model=4) mesh with the rules ``launch/train.py:build_trainer`` uses: the
